@@ -16,6 +16,7 @@ assert bit-exact recovery (DESIGN.md §17). Files written before the
 checksum existed load unchecked."""
 from __future__ import annotations
 
+import json
 import os
 import tempfile
 import zipfile
@@ -30,6 +31,10 @@ __all__ = ["CheckpointError", "checkpoint_crc", "load_pytree", "restore",
            "save", "save_pytree"]
 
 _CRC_KEY = "__crc32__"
+# npz has no descriptor for extension dtypes (bfloat16 and friends): such
+# leaves are stored as same-width unsigned views, with their dtype names
+# recorded here so a load views them back bit for bit
+_DTYPES_KEY = "__dtypes__"
 
 
 class CheckpointError(RuntimeError):
@@ -43,12 +48,18 @@ class CheckpointError(RuntimeError):
 
 
 def _flatten(tree) -> Dict[str, np.ndarray]:
-    flat = {}
+    flat, ext = {}, {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         key = "/".join(
             str(p.key) if isinstance(p, jax.tree_util.DictKey)
             else str(getattr(p, "idx", p)) for p in path)
-        flat[key] = np.asarray(leaf)
+        arr = np.asarray(leaf)
+        if arr.dtype.str[1] == "V":          # no npz descriptor: store bits
+            ext[key] = arr.dtype.name
+            arr = arr.view(f"u{arr.dtype.itemsize}")
+        flat[key] = arr
+    if ext:
+        flat[_DTYPES_KEY] = np.asarray(json.dumps(ext, sort_keys=True))
     return flat
 
 
@@ -110,6 +121,9 @@ def load_pytree(path: str, like) -> Any:
             raise CheckpointError(
                 path, f"content CRC mismatch: stored {stored_crc:#010x}, "
                       f"computed {computed:#010x} (silent bit-rot)")
+    ext = json.loads(str(flat.pop(_DTYPES_KEY, "{}")))
+    for key, name in ext.items():
+        flat[key] = flat[key].view(jnp.dtype(name))
     leaves_like, treedef = jax.tree_util.tree_flatten_with_path(like)
     out = []
     for path_keys, leaf in leaves_like:

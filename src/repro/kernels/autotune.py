@@ -318,10 +318,8 @@ def _tune_flash_decode(classes, candidates, iters: int, interpret: bool):
         rows = []
         for bk in candidates:
             def f(q, k, v, lengths, bk=bk):
-                return _decode.flash_decode(
-                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                    v.transpose(0, 2, 1, 3), lengths, block_k=bk,
-                    interpret=interpret).transpose(0, 2, 1, 3)
+                return _decode.flash_decode(q, k, v, lengths, block_k=bk,
+                                            interpret=interpret)
             rows.append({"backend": "kernel", "block_k": bk,
                          "t": _time(jax.jit(f), (q, k, v, lengths), iters)})
         rows.append({"backend": "ref",
@@ -352,15 +350,14 @@ def _tune_flash_decode_paged(classes, candidates, iters: int,
         n_pages = b * p_tab
         ks = jax.random.split(jax.random.PRNGKey(3), 3)
         q = jax.random.normal(ks[0], (b, 1, h, d))
-        k_pool = jax.random.normal(ks[1], (n_pages, ps, h_kv, d))
-        v_pool = jax.random.normal(ks[2], (n_pages, ps, h_kv, d))
+        k_pool = jax.random.normal(ks[1], (n_pages, ps, h_kv * d))
+        v_pool = jax.random.normal(ks[2], (n_pages, ps, h_kv * d))
         pages = jnp.arange(n_pages, dtype=jnp.int32).reshape(b, p_tab)
         lengths = jnp.linspace(1, p_tab * ps, b).astype(jnp.int32)
 
         def kern(q, k_pool, v_pool, pages, lengths):
-            return _decode.flash_decode_paged(
-                q.transpose(0, 2, 1, 3), k_pool, v_pool, pages, lengths,
-                interpret=interpret).transpose(0, 2, 1, 3)
+            return _decode.flash_decode_paged(q, k_pool, v_pool, pages,
+                                              lengths, interpret=interpret)
 
         rows = [
             {"backend": "kernel",

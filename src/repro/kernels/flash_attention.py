@@ -21,8 +21,15 @@ split into two kernels so each output has a sequential accumulation
 dimension innermost: the dq kernel iterates kv blocks innermost (dq tile
 accumulates in VMEM), the dk/dv kernel iterates q blocks innermost
 (dk/dv tiles accumulate in VMEM). D is a cheap fused jnp rowsum outside
-the kernels. Everything is wired through ``jax.custom_vjp`` in
-``flash_attention`` below, so ``jax.grad`` works natively on TPU and in
+the kernels.
+
+TPU layout: a block's last two dims must be (8, 128) multiples or the
+full array dims, so per-row statistics never travel as ``(1, BQ)`` slices
+of a ``(B*H, S)`` array.  ``lse`` and D are stored lane-dense as
+``(B*H, 1, S)`` (one ``(1, BQ)`` row per q block) and converted to/from
+the ``(BQ, 1)`` column the tile math uses (``tiles.py``); the running
+``m``/``l`` scratch is ``(BQ, 1)``. Everything is wired through
+``jax.custom_vjp`` in ``flash_attention`` below, so ``jax.grad`` works natively on TPU and in
 ``interpret=True`` mode on CPU.
 
 Block shapes are MXU-aligned (multiples of 128 on the matmul dims); the
@@ -50,15 +57,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tiles import col_to_row, row_to_col
+
 NEG_INF = -1e30
 
 
-def _score_mask(qi, ki, block_q, block_k, *, causal, window, seq_len):
+def _score_mask(qi, ki, block_q, block_k, *, causal, window, seq_len,
+                q_offset=0):
     """(BQ, BK) validity mask for the score tile at (q block qi, kv block
     ki). ``seq_len`` masks zero-padded kv columns (qpos >= seq_len rows
     are garbage by design — their outputs/cotangents are sliced/zeroed
-    outside the kernel)."""
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
+    outside the kernel). ``q_offset`` is the absolute position of query
+    row 0."""
+    qpos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     kpos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
@@ -75,7 +86,8 @@ def _score_mask(qi, ki, block_q, block_k, *, causal, window, seq_len):
 # ---------------------------------------------------------------------- #
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                       acc_ref, *, block_q: int, block_k: int, causal: bool,
-                      window: int, seq_len: int, n_kv_blocks: int):
+                      window: int, seq_len: int, n_kv_blocks: int,
+                      q_offset: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -92,16 +104,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     s = jnp.dot(q * (d ** -0.5), k.T,
                 preferred_element_type=jnp.float32)  # (BQ, BK)
     mask = _score_mask(qi, ki, block_q, block_k, causal=causal,
-                       window=window, seq_len=seq_len)
+                       window=window, seq_len=seq_len, q_offset=q_offset)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
+    m_prev = m_ref[...]                            # (BQ, 1)
     l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+    l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
         p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
     l_ref[...] = l_new
@@ -109,38 +121,42 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     @pl.when(ki == n_kv_blocks - 1)
     def _finalize():
         l = l_ref[...]
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         # lse = m + log(l); fully-masked rows get 0 so the backward's
         # exp(NEG_INF - lse) recompute stays exactly 0 (no inf * 0).
-        lse_ref[0] = jnp.where(l > 0, m_ref[...] + jnp.log(
-            jnp.maximum(l, 1e-30)), 0.0)
+        # Stored lane-dense as a (1, BQ) row.
+        lse_ref[0] = col_to_row(jnp.where(l > 0, m_ref[...] + jnp.log(
+            jnp.maximum(l, 1e-30)), 0.0))
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=0,
                         block_q=128, block_k=128, interpret=False,
-                        seq_len=None, return_lse=False):
-    """q/k/v: (B, H, S, D) -> (B, H, S, D) [, lse (B, H, S) f32].
+                        seq_len=None, return_lse=False, q_offset=0):
+    """q: (B, H, Sq, D), k/v: (B, H, Sk, D) -> (B, H, Sq, D) [, lse
+    (B, H, 1, Sq) f32].
 
     Raw divisible-shape primitive; ``flash_attention`` below adds padding
     and the custom VJP. ``seq_len`` masks kv positions >= seq_len (used
-    when S includes zero padding)."""
-    b, h, s, d = q.shape
+    when Sk includes zero padding); ``q_offset`` is the absolute position
+    of query row 0 (suffix prefill: Sq < Sk)."""
+    b, h, sq, d = q.shape
+    s = k.shape[2]
     assert k.shape == v.shape == (b, h, s, d)
-    block_q = min(block_q, s)
+    block_q = min(block_q, sq)
     block_k = min(block_k, s)
-    assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
+    assert sq % block_q == 0 and s % block_k == 0, (sq, s, block_q, block_k)
     if seq_len is None:
         seq_len = s
-    nq, nk = s // block_q, s // block_k
+    nq, nk = sq // block_q, s // block_k
     bh = b * h
-    qr = q.reshape(bh, s, d)
+    qr = q.reshape(bh, sq, d)
     kr = k.reshape(bh, s, d)
     vr = v.reshape(bh, s, d)
 
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k,
-        causal=causal, window=window, seq_len=seq_len, n_kv_blocks=nk)
+        causal=causal, window=window, seq_len=seq_len, n_kv_blocks=nk,
+        q_offset=q_offset)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
@@ -151,30 +167,30 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, qi, ki: (bh, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),      # running max m
-            pltpu.VMEM((block_q,), jnp.float32),      # running sum l
+            pltpu.VMEM((block_q, 1), jnp.float32),    # running max m
+            pltpu.VMEM((block_q, 1), jnp.float32),    # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),    # accumulator
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    out = out.reshape(b, h, s, d)
+    out = out.reshape(b, h, sq, d)
     if return_lse:
-        return out, lse.reshape(b, h, s)
+        return out, lse.reshape(b, h, 1, sq)
     return out
 
 
 # ---------------------------------------------------------------------- #
 # backward
 # ---------------------------------------------------------------------- #
-def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki, block_q, block_k, *,
-                    causal, window, seq_len):
+def _recompute_p_ds(q, k, v, do, lse_row, delta_row, qi, ki, block_q,
+                    block_k, *, causal, window, seq_len):
     """Shared bwd tile math: P = exp(s - lse) and dS = P * (dP - D)."""
     d = q.shape[-1]
     s = jnp.dot(q * (d ** -0.5), k.T,
@@ -182,9 +198,9 @@ def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki, block_q, block_k, *,
     mask = _score_mask(qi, ki, block_q, block_k, causal=causal,
                        window=window, seq_len=seq_len)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                      # masked entries -> 0
+    p = jnp.exp(s - row_to_col(lse_row))              # masked entries -> 0
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - row_to_col(delta_row))
     return p, ds
 
 
@@ -257,18 +273,18 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
         seq_len = s
     nq, nk = s // block_q, s // block_k
     bh = b * h
-    qr, kr, vr = (t.reshape(bh, s, d) for t in (q, k, v))
-    dor = do.reshape(bh, s, d)
-    lser = lse.reshape(bh, s)
+    qr, kr, vr, dor = (t.reshape(bh, s, d) for t in (q, k, v, do))
+    lser = lse.reshape(bh, 1, s)
     # D_i = rowsum(dO_i * O_i): cheap fused elementwise outside the grid.
     delta = jnp.sum(dor.astype(jnp.float32)
-                    * o.reshape(bh, s, d).astype(jnp.float32), axis=-1)
+                    * o.reshape(bh, s, d).astype(jnp.float32),
+                    axis=-1).reshape(bh, 1, s)
 
     common = dict(block_q=block_q, block_k=block_k, causal=causal,
                   window=window, seq_len=seq_len)
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
     k_spec = pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0))
-    row_spec = pl.BlockSpec((1, block_q), lambda bh, qi, ki: (bh, qi))
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda bh, qi, ki: (bh, 0, qi))
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_kv_blocks=nk, **common),
@@ -283,7 +299,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
     # kv blocks outermost, q blocks innermost: dk/dv accumulate in VMEM.
     tq_spec = pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0))
     tk_spec = pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
-    trow_spec = pl.BlockSpec((1, block_q), lambda bh, ki, qi: (bh, qi))
+    trow_spec = pl.BlockSpec((1, 1, block_q), lambda bh, ki, qi: (bh, 0, qi))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, n_q_blocks=nq, **common),
         grid=(bh, nk, nq),
@@ -362,6 +378,44 @@ def flash_attention_hbm_bytes(b, h, s, d, *, block_q=128, block_k=128,
     return out
 
 
+def _padded_len(s, block_q, block_k):
+    """(padded length, block_q, block_k) ``flash_attention`` uses for a
+    sequence of ``s`` rows."""
+    bq, bk = min(block_q, s), min(block_k, s)
+    if s % bq or s % bk:
+        lcm = math.lcm(block_q, block_k)
+        s = lcm * pl.cdiv(s, lcm)
+        bq, bk = min(block_q, s), min(block_k, s)
+    return s, bq, bk
+
+
+def flash_attention_extend(q, k, v, *, q_offset, block_q=128, block_k=128,
+                           interpret=False):
+    """Causal attention of suffix queries over a whole prefix + suffix
+    (suffix prefill; forward only).  q: (B, H, Sq, D) at absolute
+    positions ``[q_offset, q_offset + Sq)``; k/v: (B, H, q_offset + Sq, D).
+
+    The keys are padded and blocked exactly as ``flash_attention`` pads
+    them for the full sequence, so each query row reduces over the same
+    kv blocks in the same order as the full prefill's row; only the
+    suffix's q blocks are computed."""
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    assert sk == q_offset + sq, (sk, q_offset, sq)
+    skp, bq, bk = _padded_len(sk, block_q, block_k)
+    if skp > sk:
+        pad = ((0, 0), (0, 0), (0, skp - sk), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+    bq = min(bq, 8 * pl.cdiv(sq, 8))         # a q block: 8-row multiple
+    sqp = bq * pl.cdiv(sq, bq)
+    if sqp > sq:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, sqp - sq), (0, 0)))
+    out = flash_attention_fwd(q, k, v, causal=True, block_q=bq,
+                              block_k=bk, interpret=interpret, seq_len=sk,
+                              q_offset=q_offset)
+    return out[:, :, :sq]
+
+
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
                     block_k=128, interpret=False):
     """Trainable flash attention, (B, H, S, D) layout, any S.
@@ -370,13 +424,10 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
     to the next block multiple and masked via the kernels' ``seq_len``
     bound; padding/slicing sit OUTSIDE the custom_vjp, so JAX's linear
     pad/slice rules zero the pad-row cotangents automatically."""
-    b, h, s, d = q.shape
-    bq, bk = min(block_q, s), min(block_k, s)
-    if s % bq or s % bk:
-        sp = math.lcm(block_q, block_k) * pl.cdiv(
-            s, math.lcm(block_q, block_k))
+    s = q.shape[2]
+    sp, bq, bk = _padded_len(s, block_q, block_k)
+    if sp > s:
         pad = ((0, 0), (0, 0), (0, sp - s), (0, 0))
         q, k, v = (jnp.pad(t, pad) for t in (q, k, v))
-        bq, bk = min(block_q, sp), min(block_k, sp)
     out = _flash_core(q, k, v, s, causal, window, bq, bk, interpret)
     return out[:, :, :s]

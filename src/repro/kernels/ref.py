@@ -56,7 +56,7 @@ def flash_decode_ref(q, k, v, lengths):
 def flash_decode_paged_ref(q, k_pool, v_pool, pages, lengths):
     """Paged decode attention, XLA path — *model layout*.
 
-    q: (B, 1, H, D); k_pool/v_pool: (N_pages, page_size, H_kv, D) shared
+    q: (B, 1, H, D); k_pool/v_pool: (N_pages, page_size, H_kv*D) shared
     pools; pages: (B, P) block tables (-1 = unassigned); lengths: (B,)
     valid rows.  Gathers each slot's pages into a linear cache (-1 rows
     are gathered from page 0 but masked by ``lengths`` — the engine only
@@ -65,8 +65,9 @@ def flash_decode_paged_ref(q, k_pool, v_pool, pages, lengths):
     ``ops.flash_decode_paged`` here the paged serving path stays BITWISE
     identical to the engine's jnp path."""
     b, p_tab = pages.shape
-    n_pages, ps, h_kv, d = k_pool.shape
-    h = q.shape[2]
+    h, d = q.shape[2], q.shape[3]
+    n_pages, ps, width = k_pool.shape
+    h_kv = width // d
     safe = jnp.maximum(pages, 0)
     k = k_pool[safe].reshape(b, p_tab * ps, h_kv, d)
     v = v_pool[safe].reshape(b, p_tab * ps, h_kv, d)

@@ -25,12 +25,14 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.checkpoint import CheckpointError, checkpoint_crc
 from repro.launch.cluster import ScheduleExecutor
 from repro.launch.wire import (MessageReader, WireError, send_msg,
                                spec_from_wire)
+from repro.util.compile_cache import enable_compile_cache
 
 __all__ = ["AgentRuntime", "agent_main"]
 
@@ -256,6 +258,11 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("--id", default=f"a{os.getpid()}")
     ap.add_argument("--heartbeat", type=float, default=0.25)
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    # take the device before registering: a backend that cannot start
+    # (a chip held elsewhere) then misses the master's spawn deadline
+    # instead of stalling the first lease
+    jax.devices()
     agent_main(args.host, args.port, args.id,
                heartbeat_interval=args.heartbeat)
 

@@ -21,8 +21,9 @@ timeline of schedule events:
 
 Fused programs are AOT-compiled (``jit(...).lower(...).compile()``) and
 cached by group composition — (arch config, accumulation count, batch,
-seq) per member — so compile time never pollutes the measured walltimes
-and a recurring composition costs one compile per executor.
+seq, kernel use) per member — so compile time never pollutes the
+measured walltimes and a recurring composition costs one compile per
+executor.
 
 :func:`plan_from_sim` closes the loop with the simulator: it replays a
 ``Simulator`` event log into a :class:`SchedulePlan` — phases between
@@ -131,9 +132,11 @@ class JobSpec:
     accum_steps: int = 1        # gradient-accumulation sub-steps
     seq: int = 128
     seed: int = 0
+    use_kernels: bool = False   # Pallas attention/SSD kernels in the step
 
     def train_config(self) -> TrainConfig:
-        return TrainConfig(accum_steps=self.accum_steps)
+        return TrainConfig(accum_steps=self.accum_steps,
+                           use_kernels=self.use_kernels)
 
 
 def _make_state(spec: JobSpec):
@@ -198,6 +201,7 @@ class JobRun:
     last_ckpt_step: int = -1    # steps_done at the last checkpoint
     reconfigs: List[Tuple[int, int]] = field(default_factory=list)
     last_metrics: Any = field(default=None, repr=False)
+    losses: List[float] = field(default_factory=list, repr=False)
 
     def report(self) -> Dict[str, Any]:
         out = {
@@ -356,8 +360,8 @@ class ScheduleExecutor:
 
     def _program_key(self, runs: Sequence[JobRun]) -> tuple:
         return (self.donate,) + tuple(
-            (r.spec.cfg, r.accum_steps, r.spec.batch, r.spec.seq)
-            for r in runs)
+            (r.spec.cfg, r.accum_steps, r.spec.batch, r.spec.seq,
+             r.spec.use_kernels) for r in runs)
 
     def _program(self, runs: Sequence[JobRun]):
         key = self._program_key(runs)
@@ -367,14 +371,11 @@ class ScheduleExecutor:
                      for r in runs]
             fused = make_group_step(specs, donate=self.donate)
             args = self._flat_args(runs)
+            # no warm-up call: warming on throwaway zero states would
+            # hold a second copy of every member's params and optimizer
+            # state on the device — the memory sharing exists to save
             with self._ctx():
                 prog = fused.lower(*args).compile()
-                # warm the executable on throwaway zero states so the
-                # first measured call pays no first-touch cost (the real
-                # states are untouched — a warmup on them would advance
-                # training)
-                dummy = jax.tree.map(jnp.zeros_like, args)
-                jax.block_until_ready(prog(*dummy))
             self._programs[key] = prog
             self.compiles += 1
         return prog
@@ -559,6 +560,7 @@ class ScheduleExecutor:
             r.params, r.opt, r.last_metrics = out[3 * i:3 * i + 3]
             r.steps_done += 1
             losses[r.name] = float(r.last_metrics["loss"])
+            r.losses.append(losses[r.name])
             if (self.checkpoint_dir is not None and self.checkpoint_every
                     and r.steps_done % self.checkpoint_every == 0):
                 self.checkpoint(r.name)

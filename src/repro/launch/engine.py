@@ -159,8 +159,11 @@ class DecodeEngine:
         self.queue: deque = deque()
         self.outputs: Dict[int, List[int]] = {}
         self._next_rid = 0
-        self._prefill_fns: Dict[int, Any] = {}
-        self._segment_fn = jax.jit(self._make_segment_fn())
+        self._prefill_fns: Dict[Any, Any] = {}
+        # the cache is donated: a segment updates the KV pool in place
+        # instead of holding a second pool-sized copy while it runs
+        self._segment_fn = jax.jit(self._make_segment_fn(),
+                                   donate_argnums=(1,))
         # degraded-mode serving (DESIGN.md §16): per-request deadlines
         # with timeout-shedding, admission brown-out under overload, and
         # bounded retry of transient segment faults. All off by default.
@@ -359,15 +362,13 @@ class DecodeEngine:
         return seg
 
     def _prefill_fn(self, plen: int):
-        # prefix sharing pins prefill to the jnp path: the suffix-extend
-        # prefill has no kernel variant (the flash kernel assumes query
-        # row 0 is cache row 0), and hit/miss admissions must stay
-        # bitwise-consistent with each other
-        uk = self.use_kernels and not self.prefix_share
-        key = (plen, uk)
+        # hits (suffix extend) and misses (full prefill) take the same
+        # attention path, kernel or jnp, so their rows stay
+        # bitwise-consistent with each other and with a private engine
+        key = ("prefill", plen)
         fn = self._prefill_fns.get(key)
         if fn is None:
-            cfg, max_len = self.cfg, self.max_len
+            cfg, max_len, uk = self.cfg, self.max_len, self.use_kernels
 
             def run(params, tokens):
                 cache = init_cache(cfg, 1, max_len)
@@ -391,9 +392,9 @@ class DecodeEngine:
                 def take(dn, pool, pl):
                     if not pl:
                         return dn
-                    u = pool.shape[0]      # pool: (U, n_pages, ps, H, D)
+                    u = pool.shape[0]      # pool: (U, n_pages, ps, H*D)
                     rows = pool[:, pids].reshape(
-                        (u, 1, n_pg * ps) + pool.shape[3:])
+                        (u, 1, n_pg * ps) + dn.shape[3:])
                     return dn.at[:, :, :n_pg * ps].set(rows.astype(dn.dtype))
                 cache["units"] = jax.tree.map(
                     take, cache["units"], units, is_pool)
@@ -508,7 +509,8 @@ class DecodeEngine:
             gathered = self._gather_fn(n_m)(self.cache["units"], pids_m)
             logits, pcache = prefill_extend_cached(
                 self.cfg, self.params, gathered,
-                jnp.asarray(req.prompt)[None, L:], start=L)
+                jnp.asarray(req.prompt)[None, L:], start=L,
+                use_kernels=self.use_kernels)
             self.stats["prefix_hits"] += 1
             self.stats["prefill_tokens_saved"] += L
         else:
@@ -535,19 +537,26 @@ class DecodeEngine:
         page ``first_page`` (shared prefix pages before it are already
         populated); per-slot leaves (SSM state, whisper cross K/V)
         scatter into the slot axis as in the dense engine."""
-        ps = self.page_size
         n = len(pids)
-        pids_a = jnp.asarray(pids, jnp.int32)
-        lo = first_page * ps
+        key = ("scatter", n)
+        fn = self._prefill_fns.get(key)
+        if fn is None:
+            ps, is_pool = self.page_size, self._is_pool
 
-        def put(dst, src, is_pool):
-            if not is_pool:
-                return _scatter_slot_leaf(dst, src, slot)
-            u = src.shape[0]                   # src: (U, 1, max_len, H, D)
-            rows = src[:, 0, lo:lo + n * ps]
-            rows = rows.reshape((u, n, ps) + src.shape[3:])
-            return dst.at[:, pids_a].set(rows.astype(dst.dtype))
-        return jax.tree.map(put, self.cache["units"], punits, self._is_pool)
+            def run(units, punits, pids_a, lo, slot):
+                def put(dst, src, is_pool):
+                    if not is_pool:
+                        return _scatter_slot_leaf(dst, src, slot)
+                    u = src.shape[0]           # src: (U, 1, max_len, H, D)
+                    rows = jax.lax.dynamic_slice_in_dim(
+                        src[:, 0], lo, n * ps, axis=1)
+                    rows = rows.reshape((u, n, ps) + dst.shape[3:])
+                    return dst.at[:, pids_a].set(rows.astype(dst.dtype))
+                return jax.tree.map(put, units, punits, is_pool)
+            # donated: the pool is written in place, never copied
+            fn = self._prefill_fns[key] = jax.jit(run, donate_argnums=(0,))
+        return fn(self.cache["units"], punits, jnp.asarray(pids, jnp.int32),
+                  first_page * self.page_size, slot)
 
     def _fork_page(self, slot: int, j: int) -> None:
         """Copy-on-write: give ``slot`` a private copy of block-table
@@ -557,13 +566,16 @@ class DecodeEngine:
         old = int(self._pages_np[slot, j])
         new = self._take_page()                 # refs[new] = 1
         self._page_refs[old] -= 1
+        fn = self._prefill_fns.get("fork")
+        if fn is None:
+            is_pool = self._is_pool
 
-        def cp(leaf, is_pool):
-            if not is_pool:
-                return leaf
-            return leaf.at[:, new].set(leaf[:, old])
-        self.cache["units"] = jax.tree.map(
-            cp, self.cache["units"], self._is_pool)
+            def run(units, new, old):
+                return jax.tree.map(
+                    lambda leaf, pl: leaf.at[:, new].set(leaf[:, old])
+                    if pl else leaf, units, is_pool)
+            fn = self._prefill_fns["fork"] = jax.jit(run, donate_argnums=(0,))
+        self.cache["units"] = fn(self.cache["units"], new, old)
         self._pages_np[slot, j] = new
         self._slot_unique[slot] += 1
         self._committed -= 1
@@ -627,8 +639,9 @@ class DecodeEngine:
             self.stats["peak_active_slots"], int(self.active.sum()))
 
         def attempt():
-            # faults strike before the call (inputs are not donated, so
-            # a retried segment replays the identical computation)
+            # faults strike before the call, while the donated cache is
+            # still intact, so a retried segment replays the identical
+            # computation
             if self.fault_injector is not None:
                 self.fault_injector.check(self.stats["segments"],
                                           ("segment",))

@@ -48,6 +48,7 @@ The master doubles as an mgpu_server-shaped job service (submit / queue
 """
 from __future__ import annotations
 
+import glob
 import os
 import random
 import signal
@@ -65,12 +66,41 @@ from repro.launch.wire import (MessageReader, WireError, send_msg,
 from repro.util.retry import RetryPolicy, retry_call
 
 __all__ = ["AgentHandle", "ChaosKiller", "FleetConfig", "FleetError",
-           "FleetMaster", "KillSpec", "Lease", "MasterJob"]
+           "FleetMaster", "KillSpec", "Lease", "MasterJob",
+           "local_tpu_chips"]
 
 
 class FleetError(RuntimeError):
     """The fleet could not make progress (no agents, phase timeout, or
     an agent reported an unrecoverable lease error)."""
+
+
+def local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device files
+    without loading a JAX backend (a process that loads one holds the
+    chips).  0 when ``JAX_PLATFORMS`` excludes the TPU."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    accel = glob.glob("/dev/accel[0-9]*")
+    return len(accel) if accel else len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _chip_env(chip: int) -> Dict[str, str]:
+    """libtpu environment giving a process chip ``chip`` alone, as a
+    one-chip slice of its own.  libtpu's host-wide lock file admits one
+    process per host; the master's chip ledger keeps agents on distinct
+    chips instead, so the lock is lifted for them."""
+    with socket.socket() as s:            # libtpu's own slice port
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+            "CLOUD_TPU_TASK_ID": "0",
+            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
 
 
 # --------------------------------------------------------------------- #
@@ -226,6 +256,11 @@ class FleetMaster:
         self._agent_seq = 0
         self._service_queue: List[str] = []   # job names awaiting dispatch
         self.port: Optional[int] = None
+        # one TPU chip per agent process; the master itself never loads
+        # a JAX backend, so every chip is free for an agent; 0 chips
+        # (a CPU host, or JAX_PLATFORMS without tpu) spawns unpinned agents
+        self.n_chips = local_tpu_chips()
+        self._chip_of: Dict[str, int] = {}
 
     # -- lifecycle ----------------------------------------------------- #
     def start(self, n_agents: int = 0) -> "FleetMaster":
@@ -241,18 +276,39 @@ class FleetMaster:
             self.wait_for_agents(n_agents)
         return self
 
+    def _claim_chip(self, agent_id: str) -> int:
+        """Lowest chip not held by a live (or still starting) agent."""
+        busy = set()
+        for aid, chip in self._chip_of.items():
+            h = self.agents.get(aid)
+            if h is None or h.proc is None or h.proc.poll() is None:
+                busy.add(chip)
+        free = [c for c in range(self.n_chips) if c not in busy]
+        if not free:
+            raise FleetError(
+                f"all {self.n_chips} TPU chip(s) of this host are held by "
+                f"agents; agent {agent_id!r} would have to share one, and "
+                f"a chip serves one process")
+        self._chip_of[agent_id] = free[0]
+        return free[0]
+
     def spawn_agent(self, agent_id: Optional[str] = None) -> str:
         """Launch one agent subprocess pointed at this master. Its
-        stdout/stderr stream into ``<ckpt_dir>/<id>.log``."""
+        stdout/stderr stream into ``<ckpt_dir>/<id>.log``.  On a TPU host
+        each agent gets a chip of its own; spawning more agents than
+        chips raises :class:`FleetError`."""
         import repro
         with self._lock:
             if agent_id is None:
                 agent_id = f"a{self._agent_seq}"
                 self._agent_seq += 1
+            chip = self._claim_chip(agent_id) if self.n_chips else None
         # repro is a namespace package: locate its source root via __path__
         src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
         env = dict(os.environ)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        if chip is not None:
+            env.update(_chip_env(chip))
         log = open(os.path.join(self.checkpoint_dir, f"{agent_id}.log"),
                    "ab")
         proc = subprocess.Popen(

@@ -13,12 +13,20 @@ import to obtain placeholder devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the sharding hooks place activations with
+    # ``with_sharding_constraint``, which Explicit axes (the default of
+    # ``jax.make_mesh``) reject.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_smoke_mesh(n_devices: int = 0):
@@ -27,7 +35,7 @@ def make_smoke_mesh(n_devices: int = 0):
     n = n_devices or len(jax.devices())
     data = max(1, n // 2)
     model = n // data
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants used by the roofline analysis (§Roofline)

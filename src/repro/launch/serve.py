@@ -16,7 +16,6 @@ serving loop, the benchmark) never re-trace.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import time
 from typing import Any, Callable, Dict, Tuple
 
@@ -25,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.util.compile_cache import enable_compile_cache
 from repro.models import (decode_step, init_cache, init_params, prefill,
                           prefill_cache_whisper, prefill_extend)
 
@@ -68,16 +68,17 @@ def prefill_one_shot(cfg, params, tokens, cache, *,
     return logits[:, -1:], cache
 
 
-def prefill_extend_cached(cfg, params, cache, tokens, *, start: int):
+def prefill_extend_cached(cfg, params, cache, tokens, *, start: int,
+                          use_kernels: bool = False):
     """Suffix prefill (prefix-shared serving, DESIGN.md §18): one jitted
     call computes rows ``[start, start+S)`` into a cache whose prefix
     rows are already populated.  ``start`` is a static Python int — it
     keys the cache entry (and the trace) so the sliced attention extent
     stays exact, which the bitwise-identity contract requires.  Returns
     (logits (B, S, V), cache)."""
-    fn = _cached(("prefill_extend", cfg, start),
+    fn = _cached(("prefill_extend", cfg, start, use_kernels),
                  lambda: jax.jit(lambda p, c, t: prefill_extend(
-                     cfg, p, c, t, start=start)))
+                     cfg, p, c, t, start=start, use_kernels=use_kernels)))
     return fn(params, cache, tokens)
 
 
@@ -187,11 +188,11 @@ def main(argv=None):
     ap.add_argument("--kernels", action="store_true",
                     help="Pallas flash-decode path (interpret mode on CPU)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    cfg = dataclasses.replace(cfg, dtype="float32")
     params = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompt = jnp.asarray(

@@ -19,6 +19,7 @@ from repro.data import SyntheticLM
 from repro.models import init_params, param_count
 from repro.sharding.hooks import activation_rules
 from repro.sharding.rules import make_rules
+from repro.util.compile_cache import enable_compile_cache
 from repro.train import (TrainConfig, adamw_init, make_jit_train_step,
                          wsd_schedule)
 
@@ -43,6 +44,7 @@ def build_argparser():
 
 def main(argv=None):
     args = build_argparser().parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -43,6 +43,7 @@ def spec_to_wire(spec: JobSpec) -> Dict[str, Any]:
         "accum_steps": spec.accum_steps,
         "seq": spec.seq,
         "seed": spec.seed,
+        "use_kernels": spec.use_kernels,
     }
 
 
@@ -53,7 +54,8 @@ def spec_from_wire(d: Dict[str, Any]) -> JobSpec:
             cfg_dict[name] = tuple(cfg_dict[name])
     return JobSpec(cfg=ArchConfig(**cfg_dict), batch=int(d["batch"]),
                    accum_steps=int(d["accum_steps"]), seq=int(d["seq"]),
-                   seed=int(d["seed"]))
+                   seed=int(d["seed"]),
+                   use_kernels=bool(d.get("use_kernels", False)))
 
 
 def send_msg(sock: socket.socket, msg: Dict[str, Any],

@@ -244,7 +244,7 @@ def attention_prefill(p, x, cos, sin, cache, *, n_heads, n_kv_heads,
 
 
 def attention_prefill_extend(p, x, cos, sin, cache, *, start, n_heads,
-                             n_kv_heads, head_dim
+                             n_kv_heads, head_dim, use_kernel: bool = False
                              ) -> Tuple[jnp.ndarray, dict]:
     """Suffix prefill (DESIGN.md §18): rows ``[0, start)`` of the linear
     cache are already populated (a shared-prefix gather); write rows
@@ -259,8 +259,10 @@ def attention_prefill_extend(p, x, cos, sin, cache, *, start, n_heads,
     matmul dispatches to a different XLA accumulation path) and the
     cache dtype equals the compute dtype (prefix rows are read back
     through the cache here, but attended uncast in full prefill).
-    Linear layout only; the flash kernel assumes q/k aligned, so this
-    path is always jnp."""
+    Linear layout only.  ``use_kernel=True`` runs the flash kernel over
+    the suffix's q blocks only, with the keys padded and blocked as the
+    full prefill's kernel call blocks them, so its rows match that call's
+    rows too."""
     b, s, _ = x.shape
     q = linear(p["wq"], x).reshape(b, s, n_heads, head_dim)
     k = linear(p["wk"], x).reshape(b, s, n_kv_heads, head_dim)
@@ -279,7 +281,12 @@ def attention_prefill_extend(p, x, cos, sin, cache, *, start, n_heads,
     groups = n_heads // n_kv_heads
     kk = _repeat_kv(ck[:, :start + s], groups)
     vv = _repeat_kv(cv[:, :start + s], groups)
-    out = full_attention(q, kk, vv, causal=True, q_offset=start)
+    if use_kernel:
+        from repro.kernels import flash_attention_ops
+        out = flash_attention_ops.flash_attention_extend(
+            q, kk, vv, q_offset=start)
+    else:
+        out = full_attention(q, kk, vv, causal=True, q_offset=start)
     out = constrain(out, "act_heads")
     return (linear(p["wo"], out.reshape(b, s, n_heads * head_dim)),
             {"k": ck, "v": cv})
@@ -296,7 +303,7 @@ def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
     so it keeps the jnp path).
 
     ``pages`` switches the cache to the PAGED layout (DESIGN.md §15):
-    cache k/v are shared pools ``(N_pages, page_size, Hkv, D)`` and
+    cache k/v are shared pools ``(N_pages, page_size, Hkv*D)`` and
     ``pages`` is the per-example block table ``(B, P)`` mapping logical
     page ``index // page_size`` to a pool page (-1 = unassigned).  Linear
     layout only (``window == 0``)."""
@@ -329,14 +336,15 @@ def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
     cv = constrain(cv, "kv_cache")
 
     groups = n_heads // n_kv_heads
-    kk = _repeat_kv(ck, groups)
-    vv = _repeat_kv(cv, groups)
     idx = index if index.ndim > 0 else index[None]
     if use_kernel and window == 0:
+        # GQA resolved inside the kernel: the cache is read unrepeated
         from repro.kernels import flash_attention_ops
         lengths = jnp.broadcast_to(idx + 1, (b,))
-        out = flash_attention_ops.flash_decode(q, kk, vv, lengths)
+        out = flash_attention_ops.flash_decode(q, ck, cv, lengths)
     else:
+        kk = _repeat_kv(ck, groups)
+        vv = _repeat_kv(cv, groups)
         scale = head_dim ** -0.5
         scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                             kk.astype(jnp.float32)) * scale
@@ -375,7 +383,7 @@ def _attention_decode_paged(p, q, k, v, cache, index, pages, *, n_heads,
     it a no-op.  A plain ``.at[-1]`` would *wrap* and corrupt the last
     pool page."""
     b = q.shape[0]
-    n_pg, page_size, _, _ = cache["k"].shape
+    n_pg, page_size, _ = cache["k"].shape
     p_tab = pages.shape[1]
     index = jnp.asarray(index)
     idx = index if index.ndim > 0 else jnp.broadcast_to(index[None], (b,))
@@ -386,9 +394,9 @@ def _attention_decode_paged(p, q, k, v, cache, index, pages, *, n_heads,
                     pages[ar, jnp.minimum(pidx, p_tab - 1)], -1)
     safe = jnp.where(pid >= 0, pid, n_pg)          # unassigned -> OOB drop
     ck = cache["k"].at[safe, off].set(
-        k[:, 0].astype(cache["k"].dtype), mode="drop")
+        k[:, 0].reshape(b, -1).astype(cache["k"].dtype), mode="drop")
     cv = cache["v"].at[safe, off].set(
-        v[:, 0].astype(cache["v"].dtype), mode="drop")
+        v[:, 0].reshape(b, -1).astype(cache["v"].dtype), mode="drop")
     # no kv_cache constrain here: the pool layout (N_pages, ...) does not
     # match the (B, S, H, D) sharding rule, and serving runs single-host
 

@@ -457,7 +457,9 @@ def init_paged_cache(cfg: ArchConfig, batch: int, max_len: int, *,
     """Paged decode cache (DESIGN.md §15): every *linear-layout* KV leaf
     — the ``{"k","v"}`` caches that ``init_cache`` allocates densely as
     ``(batch, max_len, Hkv, D)`` — becomes a shared pool
-    ``(n_pages, page_size, Hkv, D)`` addressed through one top-level
+    ``(n_pages, page_size, Hkv*D)`` (kv heads flattened into the minor
+    dim, which the TPU tiles without padding) addressed through one
+    top-level
     block table ``cache["pages"]: (batch, max_len // page_size) i32``
     (-1 = unassigned).  One table serves every attention leaf because all
     of them write the same row position each step.  Non-attention state
@@ -474,10 +476,8 @@ def init_paged_cache(cfg: ArchConfig, batch: int, max_len: int, *,
     dt = _dtype(cfg, dtype)
 
     def paged_kv(h_kv):
-        return {"k": jnp.zeros((n_pages, page_size, h_kv, cfg.head_dim),
-                               dt),
-                "v": jnp.zeros((n_pages, page_size, h_kv, cfg.head_dim),
-                               dt)}
+        shape = (n_pages, page_size, h_kv * cfg.head_dim)
+        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
     fam = cfg.family
     if fam in ("dense", "vlm"):
@@ -661,7 +661,8 @@ def prefill(cfg: ArchConfig, params, cache, tokens, *,
 
 
 def prefill_extend(cfg: ArchConfig, params, cache, tokens, *,
-                   start: int) -> Tuple[jnp.ndarray, Any]:
+                   start: int, use_kernels: bool = False
+                   ) -> Tuple[jnp.ndarray, Any]:
     """Suffix prefill (DESIGN.md §18): continue a cache whose rows
     ``[0, start)`` are already populated — the prefix-shared serving path
     gathers a request's matched prompt prefix out of the page pool and
@@ -689,7 +690,7 @@ def prefill_extend(cfg: ArchConfig, params, cache, tokens, *,
     x = embed(params["embed"], tokens, dt)
     cos, sin = _rope_tables(cfg, jnp.arange(start, start + s))
     akw = dict(n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-               head_dim=cfg.head_dim, start=start)
+               head_dim=cfg.head_dim, start=start, use_kernel=use_kernels)
 
     def unit_extend(x, p, c):
         if fam in ("dense", "vlm"):

@@ -114,6 +114,22 @@ class TestRouting:
         ref = kref.flash_decode_ref(q, k, v, lengths)
         assert (np.asarray(out) == np.asarray(ref)).all()
 
+    def test_recording_names_each_route(self):
+        """``ops.recording`` sees the interpreter on CPU and the reference
+        route a tuned "ref" entry selects; calls outside stay unseen."""
+        q, k, v, lengths = self._decode_args()
+        ops.flash_decode(q, k, v, lengths, block_k=32)
+        with ops.recording() as outer:
+            ops.flash_decode(q, k, v, lengths, block_k=32)
+            key = autotune.shape_key("flash_decode", k.shape[1],
+                                     q.shape[3], q.dtype)
+            autotune.set_table(autotune.AutotuneTable(
+                _table({key: {"backend": "ref"}})))
+            with ops.recording() as inner:
+                ops.flash_decode(q, k, v, lengths)
+        assert outer == {"flash_decode": {"interpret", "ref"}}
+        assert inner == {"flash_decode": {"ref"}}
+
     def test_kernel_entry_supplies_blocks(self):
         q, k, v, lengths = self._decode_args()
         key = autotune.shape_key("flash_decode", k.shape[1], q.shape[3],

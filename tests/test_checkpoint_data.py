@@ -155,3 +155,19 @@ def test_save_pytree_is_atomic_no_tmp_left(tmp_path):
     assert sorted(p.name for p in tmp_path.iterdir()) == ["ck.npz"]
     out = load_pytree(str(path), {"a": jnp.ones((3,))})
     assert (np.asarray(out["a"]) == 0).all()
+
+
+def test_bfloat16_leaves_roundtrip_bit_exactly(tmp_path):
+    """npz has no bfloat16 descriptor: the leaves travel as uint16 bit
+    views and load back as the same bfloat16 values, CRC-verified."""
+    tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 5),
+                                   jnp.bfloat16),
+            "f": jnp.arange(3, dtype=jnp.float32)}
+    path = str(tmp_path / "bf16.npz")
+    save_pytree(path, tree)
+    got = load_pytree(path, tree)
+    assert got["w"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got["w"]).view(np.uint16),
+                                  np.asarray(tree["w"]).view(np.uint16))
+    np.testing.assert_array_equal(np.asarray(got["f"]),
+                                  np.asarray(tree["f"]))

@@ -16,8 +16,9 @@ from repro.core import (ClusterState, InterferenceModel, Job, PerfParams,
                         Simulator)
 from repro.core.schedulers import SJF_BSBF
 from repro.launch.cluster import (JobSpec, ScheduleExecutor, plan_from_sim)
+from repro.launch import fleet as fleet_mod
 from repro.launch.fleet import (ChaosKiller, FleetConfig, FleetError,
-                                FleetMaster, KillSpec)
+                                FleetMaster, KillSpec, local_tpu_chips)
 from repro.launch.wire import (MessageReader, WireError, send_msg,
                                spec_from_wire, spec_to_wire)
 from repro.util.retry import RetryPolicy
@@ -68,6 +69,50 @@ class TestWire:
 # ===================================================================== #
 # Fake-agent harness: state machine + fencing without subprocesses
 # ===================================================================== #
+class _FakeProc:
+    """Stands in for an agent subprocess: records its environment."""
+    envs: list = []
+
+    def __init__(self, args, env=None, **kw):
+        _FakeProc.envs.append(env)
+        self.returncode = None
+
+    def poll(self):
+        return self.returncode
+
+
+class TestChipPinning:
+    def test_one_chip_per_agent_and_no_more_agents_than_chips(
+            self, tmp_path, monkeypatch):
+        monkeypatch.setattr(fleet_mod.subprocess, "Popen", _FakeProc)
+        monkeypatch.setattr(fleet_mod, "local_tpu_chips", lambda: 2)
+        _FakeProc.envs = []
+        m = FleetMaster(str(tmp_path))
+        a0 = m.spawn_agent()
+        m.spawn_agent()
+        assert [e["TPU_VISIBLE_CHIPS"] for e in _FakeProc.envs] == ["0", "1"]
+        for env in _FakeProc.envs:
+            assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+            assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert (_FakeProc.envs[0]["TPU_PROCESS_PORT"]
+                != _FakeProc.envs[1]["TPU_PROCESS_PORT"])
+        with pytest.raises(FleetError, match="chip"):
+            m.spawn_agent()
+        m.agents[a0].proc.returncode = -9      # a0 died: its chip frees
+        m.spawn_agent()
+        assert _FakeProc.envs[-1]["TPU_VISIBLE_CHIPS"] == "0"
+
+    def test_cpu_agents_are_not_pinned(self, tmp_path, monkeypatch):
+        monkeypatch.setattr(fleet_mod.subprocess, "Popen", _FakeProc)
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        _FakeProc.envs = []
+        m = FleetMaster(str(tmp_path))
+        assert m.n_chips == local_tpu_chips() == 0
+        for _ in range(3):
+            m.spawn_agent()
+        assert all("TPU_VISIBLE_CHIPS" not in e for e in _FakeProc.envs)
+
+
 class FakeAgent:
     """A hand-driven agent connection: the tests decide exactly when it
     heartbeats, replies, or goes silent."""

@@ -7,7 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import (flash_attention,
+                                          flash_attention_extend,
+                                          flash_attention_fwd)
 from repro.kernels.mamba2_scan import ssd_fwd
 from repro.kernels.ref import attention_ref, ssd_ref
 from repro.models.attention import chunked_attention, full_attention
@@ -58,6 +60,21 @@ def test_flash_attention_block_shapes(bq, bk):
     ref = attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("s,start", [(288, 256), (300, 40), (20, 14),
+                                     (256, 128)])
+def test_flash_attention_extend_rows_match_full(s, start):
+    """Suffix queries at an offset reproduce the full call's rows
+    bitwise, computing only the suffix's q blocks."""
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q, k, v = [jax.random.normal(kk, (2, 3, s, 64)) for kk in ks]
+    full = flash_attention(q, k, v, interpret=True)
+    ext = flash_attention_extend(q[:, :, start:], k, v, q_offset=start,
+                                 interpret=True)
+    assert ext.shape == (2, 3, s - start, 64)
+    np.testing.assert_array_equal(np.asarray(ext),
+                                  np.asarray(full[:, :, start:]))
 
 
 def test_model_chunked_attention_matches_ref():

@@ -38,9 +38,9 @@ class TestFlashDecodePagedKernel:
         b, h, hkv, d = 3, 4, 2, 16               # GQA groups = 2
         ps, n_pg, p_tab = 8, 11, 4               # table covers 32 rows
         q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-        pool_k = jnp.asarray(rng.standard_normal((n_pg, ps, hkv, d)),
+        pool_k = jnp.asarray(rng.standard_normal((n_pg, ps, hkv * d)),
                              jnp.float32)
-        pool_v = jnp.asarray(rng.standard_normal((n_pg, ps, hkv, d)),
+        pool_v = jnp.asarray(rng.standard_normal((n_pg, ps, hkv * d)),
                              jnp.float32)
         lengths = jnp.asarray([5, 17, 32], jnp.int32)
         pages = np.full((b, p_tab), -1, np.int32)
@@ -78,15 +78,17 @@ class TestAttentionDecodePaged:
                                   jnp.float32)}
         # build pool + tables holding the same rows as the dense cache
         pages = np.full((b, p_tab), -1, np.int32)
-        pool_k = np.zeros((n_pg, ps, nkv, hd), np.float32)
-        pool_v = np.zeros((n_pg, ps, nkv, hd), np.float32)
+        pool_k = np.zeros((n_pg, ps, nkv * hd), np.float32)
+        pool_v = np.zeros((n_pg, ps, nkv * hd), np.float32)
         free = list(range(n_pg))
         for bi in range(b):
             for pi in range(-(-(int(idx[bi]) + 1) // ps)):
                 pg = free.pop()
                 pages[bi, pi] = pg
-                pool_k[pg] = np.asarray(dense["k"][bi, pi * ps:(pi + 1) * ps])
-                pool_v[pg] = np.asarray(dense["v"][bi, pi * ps:(pi + 1) * ps])
+                pool_k[pg] = np.asarray(
+                    dense["k"][bi, pi * ps:(pi + 1) * ps]).reshape(ps, -1)
+                pool_v[pg] = np.asarray(
+                    dense["v"][bi, pi * ps:(pi + 1) * ps]).reshape(ps, -1)
         paged = {"k": jnp.asarray(pool_k), "v": jnp.asarray(pool_v)}
         kw = dict(n_heads=nh, n_kv_heads=nkv, head_dim=hd)
         return p, x, idx, dense, paged, jnp.asarray(pages), kw
@@ -117,7 +119,7 @@ class TestAttentionDecodePaged:
             pg = int(pages[bi, i // ps])
             np.testing.assert_array_equal(
                 np.asarray(cache_p["k"][pg, i % ps]),
-                np.asarray(cache_d["k"][bi, i]))
+                np.asarray(cache_d["k"][bi, i]).reshape(-1))
 
     def test_unassigned_page_write_drops(self):
         """An example whose table has no page for its index (an inactive
@@ -156,7 +158,7 @@ class TestInitPagedCache:
         assert cache["pages"].shape == (2, 4)
         assert (np.asarray(cache["pages"]) == -1).all()
         k = cache["units"]["k"]
-        assert k.shape == (cfg.n_units, 8, 8, cfg.n_kv_heads, cfg.head_dim)
+        assert k.shape == (cfg.n_units, 8, 8, cfg.n_kv_heads * cfg.head_dim)
 
 
 # ====================================================================== #

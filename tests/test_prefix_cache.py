@@ -111,9 +111,11 @@ class TestPrefixTrie:
 # suffix-extend prefill: bitwise vs the full one-shot prefill
 # ====================================================================== #
 class TestPrefillExtend:
+    @pytest.mark.parametrize("use_kernels", [False, True],
+                             ids=["jnp", "kernel"])
     @pytest.mark.parametrize("name,kw", SHARE_ARCHS,
                              ids=[a for a, _ in SHARE_ARCHS])
-    def test_bitwise_identity_suffix_ge_2(self, name, kw):
+    def test_bitwise_identity_suffix_ge_2(self, name, kw, use_kernels):
         cfg = _cfg(name, **kw)
         params = _params(cfg)
         rng = np.random.default_rng(0)
@@ -122,7 +124,8 @@ class TestPrefillExtend:
             toks = jnp.asarray(rng.integers(0, cfg.vocab, (1, plen)),
                                jnp.int32)
             lg_full, c_full = prefill(cfg, params,
-                                      init_cache(cfg, 1, max_len), toks)
+                                      init_cache(cfg, 1, max_len), toks,
+                                      use_kernels=use_kernels)
             c_pre = init_cache(cfg, 1, max_len)
 
             def take(dst, src):
@@ -132,7 +135,8 @@ class TestPrefillExtend:
             c_pre["units"] = jax.tree.map(take, c_pre["units"],
                                           c_full["units"])
             lg_ext, c_ext = prefill_extend(cfg, params, c_pre,
-                                           toks[:, start:], start=start)
+                                           toks[:, start:], start=start,
+                                           use_kernels=use_kernels)
             assert (np.asarray(lg_full[:, start:])
                     == np.asarray(lg_ext)).all(), (name, plen, start)
 
@@ -194,9 +198,11 @@ class TestPrefixEngineGating:
 
 
 class TestPrefixEngine:
+    @pytest.mark.parametrize("use_kernels", [False, True],
+                             ids=["jnp", "kernel"])
     @pytest.mark.parametrize("name,kw", SHARE_ARCHS,
                              ids=[a for a, _ in SHARE_ARCHS])
-    def test_identity_vs_private_and_solo(self, name, kw):
+    def test_identity_vs_private_and_solo(self, name, kw, use_kernels):
         """Shared-prefix tokens == private-pages tokens == solo
         generation, across every family supporting the paged layout
         with a bitwise-stable extend path."""
@@ -206,15 +212,17 @@ class TestPrefixEngine:
         shared = rng.integers(0, cfg.vocab, 20)    # 2.5 pages: COW too
         prompts = [np.concatenate([shared, rng.integers(0, cfg.vocab, 6)])
                    for _ in range(5)]
-        base = _drain(_share_engine(cfg, params, prefix_share=False),
-                      prompts)
-        eng = _share_engine(cfg, params, prefix_share=True)
+        base = _drain(_share_engine(cfg, params, prefix_share=False,
+                                    use_kernels=use_kernels), prompts)
+        eng = _share_engine(cfg, params, prefix_share=True,
+                            use_kernels=use_kernels)
         out = _drain(eng, prompts)
         assert out == base
         assert eng.stats["prefix_hits"] >= 4
         assert eng.stats["prefill_tokens_saved"] > 0
         solo = serve.generate(cfg, params, jnp.asarray(prompts[1])[None, :],
-                              max_new_tokens=8, max_len=64)
+                              max_new_tokens=8, max_len=64,
+                              use_kernels=use_kernels)
         assert list(np.asarray(solo)[0]) == out[1]
 
     def test_cow_fork_on_boundary_page(self):
@@ -348,9 +356,9 @@ class TestPagedDecodeRouting:
         rng = np.random.default_rng(7)
         b, h, hkv, d, ps, n_pg, p_tab = 2, 4, 2, 32, 8, 6, 2
         q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((n_pg, ps, hkv, d)),
+        kp = jnp.asarray(rng.standard_normal((n_pg, ps, hkv * d)),
                          jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((n_pg, ps, hkv, d)),
+        vp = jnp.asarray(rng.standard_normal((n_pg, ps, hkv * d)),
                          jnp.float32)
         pages = jnp.asarray([[0, 1], [2, -1]], jnp.int32)
         lengths = jnp.asarray([16, 5], jnp.int32)
