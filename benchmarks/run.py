@@ -1,5 +1,4 @@
-"""Benchmark harness entry point — one module per paper table/figure plus
-the roofline analysis over the dry-run artifacts.
+"""Benchmark harness entry point — one module per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--skip-slow]
 """
@@ -17,7 +16,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from . import (fig4_fig5_jct_queue, fig6a_load, fig6b_xi,
-                   replay_validation, roofline, sim_throughput,
+                   replay_validation, sim_throughput,
                    table2_physical, table3_240, table4_480, xi_calibration)
 
     stages = [
@@ -35,7 +34,6 @@ def main(argv=None) -> int:
                        replay_validation.run))
         stages.append(("sim_throughput (engine before/after)",
                        sim_throughput.run))
-    stages.append(("roofline (§Roofline from dry-run)", roofline.run))
 
     failures = 0
     for name, fn in stages:

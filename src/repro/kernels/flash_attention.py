@@ -159,6 +159,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0,
         q_offset=q_offset)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -288,6 +289,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_kv_blocks=nk, **common),
+        name="flash_attention_dq",
         grid=(bh, nq, nk),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
@@ -302,6 +304,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
     trow_spec = pl.BlockSpec((1, 1, block_q), lambda bh, ki, qi: (bh, 0, qi))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, n_q_blocks=nq, **common),
+        name="flash_attention_dkv",
         grid=(bh, nk, nq),
         in_specs=[tq_spec, tk_spec, tk_spec, tq_spec, trow_spec, trow_spec],
         out_specs=[tk_spec, tk_spec],

@@ -173,6 +173,7 @@ def flash_decode_paged(q, k_pool, v_pool, pages, lengths, *,
 
     out = pl.pallas_call(
         kernel,
+        name="flash_decode_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, p_tab),

@@ -242,6 +242,7 @@ def ssd_fwd(x, dt, A, Bm, Cm, *, chunk=256, interpret=False,
 
     out = pl.pallas_call(
         kernel,
+        name="ssd_fwd",
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
@@ -279,6 +280,7 @@ def ssd_bwd(x, dt, A, Bm, Cm, states, dy, *, chunk=256, interpret=False):
     kernel = functools.partial(_ssd_bwd_kernel, chunk=chunk)
     dx_r, ddt_r, ddA_r, dbh, dch = pl.pallas_call(
         kernel,
+        name="ssd_bwd",
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p),

@@ -54,6 +54,7 @@ from repro.configs.base import ArchConfig
 from repro.data import make_batch
 from repro.models import init_params
 from repro.train import TrainConfig, adamw_init, make_train_step
+from repro.util import spans
 from repro.util.retry import RetryPolicy, retry_call
 
 
@@ -170,7 +171,8 @@ def make_group_step(specs: Sequence[JobSpec], *, donate: bool = False):
     def group_step(*state):
         out: List[Any] = []
         for i, step in enumerate(steps):
-            p, o, m = step(*state[3 * i:3 * i + 3])
+            with jax.named_scope(f"member{i}"):
+                p, o, m = step(*state[3 * i:3 * i + 3])
             out += [p, o, m]
         return tuple(out)
 
@@ -367,15 +369,20 @@ class ScheduleExecutor:
         key = self._program_key(runs)
         prog = self._programs.get(key)
         if prog is None:
-            specs = [dataclasses.replace(r.spec, accum_steps=r.accum_steps)
-                     for r in runs]
-            fused = make_group_step(specs, donate=self.donate)
-            args = self._flat_args(runs)
-            # no warm-up call: warming on throwaway zero states would
-            # hold a second copy of every member's params and optimizer
-            # state on the device — the memory sharing exists to save
-            with self._ctx():
-                prog = fused.lower(*args).compile()
+            spans.count("program_builds")
+            with spans.span("executor.build",
+                            group="+".join(r.name for r in runs)):
+                specs = [dataclasses.replace(r.spec,
+                                             accum_steps=r.accum_steps)
+                         for r in runs]
+                fused = make_group_step(specs, donate=self.donate)
+                args = self._flat_args(runs)
+                # no warm-up call: warming on throwaway zero states would
+                # hold a second copy of every member's params and
+                # optimizer state on the device — the memory sharing
+                # exists to save
+                with self._ctx():
+                    prog = fused.lower(*args).compile()
             self._programs[key] = prog
             self.compiles += 1
         return prog
@@ -524,22 +531,38 @@ class ScheduleExecutor:
         faulting member ``failed`` and returns ``{"dropped": name}`` —
         the caller drops it and keeps stepping the survivors (the next
         fused call re-fuses automatically: programs are cached by group
-        composition)."""
+        composition).
+
+        Spans: ``executor.step_group`` holds ``executor.prepare`` (program
+        lookup, or ``executor.build`` for a new composition, and the
+        flattened arguments), ``executor.dispatch`` (until the call
+        returns), ``executor.wait`` (``block_until_ready``; the walltime
+        runs from the dispatch to here), ``executor.readback`` (the losses
+        to the host) and ``executor.bookkeep``."""
+        with spans.span("executor.step_group", call=self.calls,
+                        group="+".join(names)):
+            return self._step_group(names)
+
+    def _step_group(self, names: Sequence[str]) -> Dict[str, Any]:
         runs = [self.runs[n] for n in names]
         for r in runs:
             if not r.started or r.finished or r.failed:
                 raise RuntimeError(f"job {r.name!r} not running")
-        prog = self._program(runs)
 
         def attempt():
+            with spans.span("executor.prepare"):
+                prog = self._program(runs)
+                args = self._flat_args(runs)
             if self.fault_injector is not None:
                 self.fault_injector.check(self.calls, names)
-            args = self._flat_args(runs)
             with self._ctx():
-                t0 = time.perf_counter()
-                out = prog(*args)
-                jax.block_until_ready(out)
-                return out, time.perf_counter() - t0
+                with spans.span("executor.dispatch"):
+                    t0 = time.perf_counter()
+                    out = prog(*args)
+                with spans.span("executor.wait"):
+                    jax.block_until_ready(out)
+                    dt = time.perf_counter() - t0
+            return out, dt
 
         def note_retry(attempt_i, exc, delay):
             self.retries_total += 1
@@ -555,16 +578,18 @@ class ScheduleExecutor:
             run.failed = True
             self.drops_total += 1
             return {"walltime": 0.0, "losses": {}, "dropped": exc.job}
-        losses = {}
-        for i, r in enumerate(runs):
-            r.params, r.opt, r.last_metrics = out[3 * i:3 * i + 3]
-            r.steps_done += 1
-            losses[r.name] = float(r.last_metrics["loss"])
-            r.losses.append(losses[r.name])
-            if (self.checkpoint_dir is not None and self.checkpoint_every
-                    and r.steps_done % self.checkpoint_every == 0):
-                self.checkpoint(r.name)
-        self.calls += 1
+        with spans.span("executor.readback"):
+            losses = {r.name: float(out[3 * i + 2]["loss"])
+                      for i, r in enumerate(runs)}
+        with spans.span("executor.bookkeep"):
+            for i, r in enumerate(runs):
+                r.params, r.opt, r.last_metrics = out[3 * i:3 * i + 3]
+                r.steps_done += 1
+                r.losses.append(losses[r.name])
+                if (self.checkpoint_dir is not None and self.checkpoint_every
+                        and r.steps_done % self.checkpoint_every == 0):
+                    self.checkpoint(r.name)
+            self.calls += 1
         return {"walltime": dt, "losses": losses}
 
     def _apply(self, op: PlanOp) -> None:

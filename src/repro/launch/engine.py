@@ -21,6 +21,7 @@ request would carry its own encoder pass; use ``serve.generate``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import random
 import time
@@ -35,6 +36,7 @@ from repro.launch.cluster import ScriptedFaults, TransientFault
 from repro.launch.prefix import PrefixTrie
 from repro.launch.serve import _make_scan_generate, prefill_extend_cached
 from repro.models import init_cache, init_paged_cache, prefill
+from repro.util import spans
 from repro.util.retry import RetryPolicy, retry_call
 
 
@@ -160,6 +162,7 @@ class DecodeEngine:
         self.outputs: Dict[int, List[int]] = {}
         self._next_rid = 0
         self._prefill_fns: Dict[Any, Any] = {}
+        self._built: set = set()          # (program, shape) called once
         # the cache is donated: a segment updates the KV pool in place
         # instead of holding a second pool-sized copy while it runs
         self._segment_fn = jax.jit(self._make_segment_fn(),
@@ -176,7 +179,7 @@ class DecodeEngine:
         self.slot_deadline: List[Optional[float]] = [None] * n_slots
         self.shed: Dict[int, str] = {}        # rid -> shed reason
         self.retry_after: Dict[int, float] = {}   # rid -> backoff hint (s)
-        self._seg_ewma = 0.0                  # EWMA segment walltime (s)
+        self._seg_ewma = 0.0    # EWMA segment time, dispatch to tokens (s)
         self.stats = {"segments": 0, "admitted": 0, "wasted_slot_steps": 0,
                       "peak_active_slots": 0, "shed_deadline": 0,
                       "shed_brownout": 0, "deadline_miss": 0, "retries": 0}
@@ -356,10 +359,20 @@ class DecodeEngine:
                                   self.use_kernels)
         key = jax.random.PRNGKey(0)
 
-        def seg(params, cache, tok):
+        def decode_segment(params, cache, tok):
             toks, cache, tok, _ = run(params, cache, tok, key)
             return toks, cache, tok
-        return seg
+        return decode_segment
+
+    def _first_call(self, kind: str, shape):
+        """An ``engine.build`` span around the first call of program
+        ``kind`` at ``shape`` (where it compiles, or loads from the
+        persistent cache), counted in ``program_builds``; else nothing."""
+        if (kind, shape) in self._built:
+            return contextlib.nullcontext()
+        self._built.add((kind, shape))
+        spans.count("program_builds")
+        return spans.span("engine.build", kind=kind, shape=str(shape))
 
     def _prefill_fn(self, plen: int):
         # hits (suffix extend) and misses (full prefill) take the same
@@ -373,7 +386,7 @@ class DecodeEngine:
             def run(params, tokens):
                 cache = init_cache(cfg, 1, max_len)
                 return prefill(cfg, params, cache, tokens, use_kernels=uk)
-            fn = self._prefill_fns[key] = jax.jit(run)
+            fn = self._prefill_fns[key] = jax.jit(spans.named("prefill", run))
         return fn
 
     def _gather_fn(self, n_pg: int):
@@ -386,7 +399,7 @@ class DecodeEngine:
             cfg, max_len, ps = self.cfg, self.max_len, self.page_size
             is_pool = self._is_pool
 
-            def run(units, pids):
+            def gather_prefix(units, pids):
                 cache = init_cache(cfg, 1, max_len)
 
                 def take(dn, pool, pl):
@@ -399,7 +412,7 @@ class DecodeEngine:
                 cache["units"] = jax.tree.map(
                     take, cache["units"], units, is_pool)
                 return cache
-            fn = self._prefill_fns[key] = jax.jit(run)
+            fn = self._prefill_fns[key] = jax.jit(gather_prefix)
         return fn
 
     # ------------------------------------------------------------------ #
@@ -449,6 +462,7 @@ class DecodeEngine:
         for slot in range(self.n_slots):
             if self.active[slot] or not self.queue:
                 continue
+            reserve = match = None
             if self.paged:
                 req = self.queue[0]
                 reserve, match = self._plan_admission(req)
@@ -456,24 +470,33 @@ class DecodeEngine:
                     self.stats["admission_deferred_pages"] += 1
                     break
                 self.queue.popleft()
-                logits = self._admit_paged(slot, req, reserve, match)
             else:
                 req = self.queue.popleft()
-                plen = req.prompt.shape[0]
-                assert plen <= self.max_len
+            with spans.span("engine.admit", rid=req.rid,
+                            matched=match[1] if match is not None else 0,
+                            plen=req.prompt.shape[0]):
+                self._admit_one(slot, req, reserve, match)
+
+    def _admit_one(self, slot: int, req: Request, reserve, match) -> None:
+        """Prefill ``req`` into ``slot`` and make the slot decode it."""
+        plen = req.prompt.shape[0]
+        if self.paged:
+            logits = self._admit_paged(slot, req, reserve, match)
+        else:
+            assert plen <= self.max_len
+            with self._first_call("prefill", plen):
                 logits, pcache = self._prefill_fn(plen)(
                     self.params, jnp.asarray(req.prompt)[None, :])
-                self.cache["units"] = _scatter_slot(
-                    self.cache["units"], pcache["units"], slot)
-            plen = req.prompt.shape[0]
-            self.cache["index"] = self.cache["index"].at[slot].set(plen)
-            first = jnp.argmax(logits[0, -1]).astype(jnp.int32)
-            self.tok = self.tok.at[slot, 0].set(first)
-            self.active[slot] = True
-            self.remaining[slot] = req.max_new_tokens
-            self.slot_rid[slot] = req.rid
-            self.slot_deadline[slot] = req.deadline
-            self.stats["admitted"] += 1
+            self.cache["units"] = _scatter_slot(
+                self.cache["units"], pcache["units"], slot)
+        self.cache["index"] = self.cache["index"].at[slot].set(plen)
+        first = jnp.argmax(logits[0, -1]).astype(jnp.int32)
+        self.tok = self.tok.at[slot, 0].set(first)
+        self.active[slot] = True
+        self.remaining[slot] = req.max_new_tokens
+        self.slot_rid[slot] = req.rid
+        self.slot_deadline[slot] = req.deadline
+        self.stats["admitted"] += 1
 
     def _admit_paged(self, slot: int, req: Request, reserve: int, match):
         """Paged admission: map the fully-matched shared prefix pages
@@ -506,16 +529,19 @@ class DecodeEngine:
             # only (never mapped) — then extend from row L
             n_m = -(-L // ps)
             pids_m = jnp.asarray([int(p) for p in pages_m[:n_m]], jnp.int32)
-            gathered = self._gather_fn(n_m)(self.cache["units"], pids_m)
-            logits, pcache = prefill_extend_cached(
-                self.cfg, self.params, gathered,
-                jnp.asarray(req.prompt)[None, L:], start=L,
-                use_kernels=self.use_kernels)
+            with self._first_call("gather_prefix", n_m):
+                gathered = self._gather_fn(n_m)(self.cache["units"], pids_m)
+            with self._first_call("prefill_extend", (L, plen - L)):
+                logits, pcache = prefill_extend_cached(
+                    self.cfg, self.params, gathered,
+                    jnp.asarray(req.prompt)[None, L:], start=L,
+                    use_kernels=self.use_kernels)
             self.stats["prefix_hits"] += 1
             self.stats["prefill_tokens_saved"] += L
         else:
-            logits, pcache = self._prefill_fn(plen)(
-                self.params, jnp.asarray(req.prompt)[None, :])
+            with self._first_call("prefill", plen):
+                logits, pcache = self._prefill_fn(plen)(
+                    self.params, jnp.asarray(req.prompt)[None, :])
             if self.prefix_share:
                 self.stats["prefix_misses"] += 1
         if self.prefix_share:
@@ -543,7 +569,7 @@ class DecodeEngine:
         if fn is None:
             ps, is_pool = self.page_size, self._is_pool
 
-            def run(units, punits, pids_a, lo, slot):
+            def scatter_pages(units, punits, pids_a, lo, slot):
                 def put(dst, src, is_pool):
                     if not is_pool:
                         return _scatter_slot_leaf(dst, src, slot)
@@ -554,9 +580,12 @@ class DecodeEngine:
                     return dst.at[:, pids_a].set(rows.astype(dst.dtype))
                 return jax.tree.map(put, units, punits, is_pool)
             # donated: the pool is written in place, never copied
-            fn = self._prefill_fns[key] = jax.jit(run, donate_argnums=(0,))
-        return fn(self.cache["units"], punits, jnp.asarray(pids, jnp.int32),
-                  first_page * self.page_size, slot)
+            fn = self._prefill_fns[key] = jax.jit(scatter_pages,
+                                                  donate_argnums=(0,))
+        with self._first_call("scatter_pages", n):
+            return fn(self.cache["units"], punits,
+                      jnp.asarray(pids, jnp.int32),
+                      first_page * self.page_size, slot)
 
     def _fork_page(self, slot: int, j: int) -> None:
         """Copy-on-write: give ``slot`` a private copy of block-table
@@ -570,12 +599,14 @@ class DecodeEngine:
         if fn is None:
             is_pool = self._is_pool
 
-            def run(units, new, old):
+            def fork_page(units, new, old):
                 return jax.tree.map(
                     lambda leaf, pl: leaf.at[:, new].set(leaf[:, old])
                     if pl else leaf, units, is_pool)
-            fn = self._prefill_fns["fork"] = jax.jit(run, donate_argnums=(0,))
-        self.cache["units"] = fn(self.cache["units"], new, old)
+            fn = self._prefill_fns["fork"] = jax.jit(fork_page,
+                                                     donate_argnums=(0,))
+        with self._first_call("fork_page", ()):
+            self.cache["units"] = fn(self.cache["units"], new, old)
         self._pages_np[slot, j] = new
         self._slot_unique[slot] += 1
         self._committed -= 1
@@ -609,10 +640,24 @@ class DecodeEngine:
         """One fused scan segment + post-segment bookkeeping/admission.
         Degraded-mode pre-pass: expired requests shed (queued and
         active) and the queue brown-outs before admission refills the
-        freed slots."""
-        self._shed_expired(self._clock())
-        self._brownout()
-        self._admit()
+        freed slots.
+
+        Spans: ``engine.step_segment`` holds ``engine.shed``, one
+        ``engine.admit`` per admission, ``engine.grow`` (page growth,
+        copy-on-write forks, the block-table push), ``engine.dispatch``,
+        ``engine.wait`` (until the tokens are on the host) and
+        ``engine.bookkeep`` (outputs, frees, stats)."""
+        with spans.span("engine.step_segment",
+                        segment=self.stats["segments"]):
+            with spans.span("engine.shed"):
+                self._shed_expired(self._clock())
+                self._brownout()
+            self._admit()
+            with spans.span("engine.grow"):
+                self._grow_and_push()
+            self._decode()
+
+    def _grow_and_push(self) -> None:
         if self.paged:
             self._grow()
             # one host->device push of the (n_slots, P) block table per
@@ -638,6 +683,8 @@ class DecodeEngine:
         self.stats["peak_active_slots"] = max(
             self.stats["peak_active_slots"], int(self.active.sum()))
 
+    def _decode(self) -> None:
+        """Run the segment, wait for its tokens, and hand them out."""
         def attempt():
             # faults strike before the call, while the donated cache is
             # still intact, so a retried segment replays the identical
@@ -647,16 +694,25 @@ class DecodeEngine:
                                           ("segment",))
             return self._segment_fn(self.params, self.cache, self.tok)
 
-        t0 = time.perf_counter()
-        toks, self.cache, self.tok = retry_call(
-            attempt, policy=self.retry_policy, retry_on=(TransientFault,),
-            rng=self._retry_rng, sleep=self._sleep,
-            on_retry=lambda *_: self.stats.__setitem__(
-                "retries", self.stats["retries"] + 1))
-        dt = time.perf_counter() - t0
+        with spans.span("engine.dispatch"):
+            t0 = time.perf_counter()
+            with self._first_call("decode_segment", ()):
+                toks, self.cache, self.tok = retry_call(
+                    attempt, policy=self.retry_policy,
+                    retry_on=(TransientFault,), rng=self._retry_rng,
+                    sleep=self._sleep,
+                    on_retry=lambda *_: self.stats.__setitem__(
+                        "retries", self.stats["retries"] + 1))
+        with spans.span("engine.wait"):
+            toks = np.asarray(toks)                 # (n_slots, segment)
+            dt = time.perf_counter() - t0
+        # the segment's time runs from dispatch to its tokens on the host
         self._seg_ewma = (dt if self._seg_ewma == 0.0
                           else 0.2 * dt + 0.8 * self._seg_ewma)
-        toks = np.asarray(toks)                     # (n_slots, segment)
+        with spans.span("engine.bookkeep"):
+            self._hand_out(toks)
+
+    def _hand_out(self, toks: np.ndarray) -> None:
         self.stats["segments"] += 1
         self.stats["wasted_slot_steps"] += int(
             (~self.active).sum()) * self.segment

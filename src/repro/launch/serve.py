@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
 from repro.util.compile_cache import enable_compile_cache
+from repro.util.spans import named
 from repro.models import (decode_step, init_cache, init_params, prefill,
                           prefill_cache_whisper, prefill_extend)
 
@@ -53,8 +54,9 @@ def clear_jit_cache() -> None:
 def _decode_step_fn(cfg, use_kernels: bool) -> Callable:
     """Cache keys hold only trace-affecting Python values; jax.jit keys
     the input shapes itself, so the dict stays bounded per config."""
-    return _cached(("step", cfg, use_kernels), lambda: jax.jit(
-        lambda p, c, t: decode_step(cfg, p, c, t, use_kernels=use_kernels)))
+    return _cached(("step", cfg, use_kernels), lambda: jax.jit(named(
+        "decode_step", lambda p, c, t: decode_step(
+            cfg, p, c, t, use_kernels=use_kernels))))
 
 
 def prefill_one_shot(cfg, params, tokens, cache, *,
@@ -62,8 +64,8 @@ def prefill_one_shot(cfg, params, tokens, cache, *,
     """Single-shot prefill: one jitted call populates the whole cache.
     Returns (last-position logits (B, 1, V), cache)."""
     fn = _cached(("prefill", cfg, use_kernels),
-                 lambda: jax.jit(lambda p, c, t: prefill(
-                     cfg, p, c, t, use_kernels=use_kernels)))
+                 lambda: jax.jit(named("prefill", lambda p, c, t: prefill(
+                     cfg, p, c, t, use_kernels=use_kernels))))
     logits, cache = fn(params, cache, tokens)
     return logits[:, -1:], cache
 
@@ -77,8 +79,10 @@ def prefill_extend_cached(cfg, params, cache, tokens, *, start: int,
     stays exact, which the bitwise-identity contract requires.  Returns
     (logits (B, S, V), cache)."""
     fn = _cached(("prefill_extend", cfg, start, use_kernels),
-                 lambda: jax.jit(lambda p, c, t: prefill_extend(
-                     cfg, p, c, t, start=start, use_kernels=use_kernels)))
+                 lambda: jax.jit(named(
+                     "prefill_extend", lambda p, c, t: prefill_extend(
+                         cfg, p, c, t, start=start,
+                         use_kernels=use_kernels))))
     return fn(params, cache, tokens)
 
 
@@ -101,7 +105,7 @@ def _make_scan_generate(cfg, steps: int, greedy: bool, use_kernels: bool):
     exact op/key order of the eager loop, so outputs are bit-identical.
     Returns (tokens (B, steps), cache, next token, key) so callers that
     segment generation (``launch/engine.py``) can continue the carry."""
-    def run(params, cache, tok, key):
+    def decode_segment(params, cache, tok, key):
         def body(carry, _):
             cache, tok, key = carry
             logits, cache = decode_step(cfg, params, cache, tok,
@@ -116,7 +120,7 @@ def _make_scan_generate(cfg, steps: int, greedy: bool, use_kernels: bool):
         (cache, tok, key), toks = jax.lax.scan(
             body, (cache, tok, key), length=steps)
         return jnp.moveaxis(toks[:, :, 0], 0, 1), cache, tok, key
-    return run
+    return decode_segment
 
 
 def generate(cfg, params, prompt, *, max_new_tokens=16, max_len=256,

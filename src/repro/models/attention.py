@@ -324,16 +324,17 @@ def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
     s_cache = cache["k"].shape[1]
     index = jnp.asarray(index)
     slot = index % s_cache if window > 0 else index  # ring buffer vs linear
-    if index.ndim == 0:
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
-    else:
-        ck = _scatter_rows(cache["k"], k, slot)
-        cv = _scatter_rows(cache["v"], v, slot)
-    ck = constrain(ck, "kv_cache")
-    cv = constrain(cv, "kv_cache")
+    with jax.named_scope("kv_write"):
+        if index.ndim == 0:
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
+        else:
+            ck = _scatter_rows(cache["k"], k, slot)
+            cv = _scatter_rows(cache["v"], v, slot)
+        ck = constrain(ck, "kv_cache")
+        cv = constrain(cv, "kv_cache")
 
     groups = n_heads // n_kv_heads
     idx = index if index.ndim > 0 else index[None]
@@ -393,10 +394,11 @@ def _attention_decode_paged(p, q, k, v, cache, index, pages, *, n_heads,
     pid = jnp.where(pidx < p_tab,
                     pages[ar, jnp.minimum(pidx, p_tab - 1)], -1)
     safe = jnp.where(pid >= 0, pid, n_pg)          # unassigned -> OOB drop
-    ck = cache["k"].at[safe, off].set(
-        k[:, 0].reshape(b, -1).astype(cache["k"].dtype), mode="drop")
-    cv = cache["v"].at[safe, off].set(
-        v[:, 0].reshape(b, -1).astype(cache["v"].dtype), mode="drop")
+    with jax.named_scope("kv_write"):
+        ck = cache["k"].at[safe, off].set(
+            k[:, 0].reshape(b, -1).astype(cache["k"].dtype), mode="drop")
+        cv = cache["v"].at[safe, off].set(
+            v[:, 0].reshape(b, -1).astype(cache["v"].dtype), mode="drop")
     # no kv_cache constrain here: the pool layout (N_pages, ...) does not
     # match the (B, S, H, D) sharding rule, and serving runs single-host
 

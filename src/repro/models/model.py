@@ -769,11 +769,13 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
     def unit_step(x, p, c):
         new_c = c
         if fam in ("dense", "vlm"):
-            h, kv = attn_mod.attention_decode(
-                p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps),
-                cos, sin, c, idx, **akw)
-            x = x + h
-            x = x + swiglu(p["mlp"], rms_norm(p["ln2"], x, cfg.norm_eps))
+            with jax.named_scope("attention"):
+                h, kv = attn_mod.attention_decode(
+                    p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps),
+                    cos, sin, c, idx, **akw)
+                x = x + h
+            with jax.named_scope("mlp"):
+                x = x + swiglu(p["mlp"], rms_norm(p["ln2"], x, cfg.norm_eps))
             new_c = kv
         elif fam == "moe":
             new_c = dict(c)
@@ -855,8 +857,9 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
 
     x = constrain(x, "act_btd")
     x, new_units = jax.lax.scan(body, x, (params["units"], cache["units"]))
-    x = rms_norm(params["ln_f"], x, cfg.norm_eps)
-    logits = _lm_head(cfg, params, x)
+    with jax.named_scope("head"):
+        x = rms_norm(params["ln_f"], x, cfg.norm_eps)
+        logits = _lm_head(cfg, params, x)
     new_cache = dict(cache)
     new_cache["units"] = new_units
     new_cache["index"] = idx + 1

@@ -79,8 +79,9 @@ def accumulate_gradients(
         def step(carry, mb):
             loss_acc, grads_acc = carry
             loss, grads = loss_and_grad(params, mb)
-            grads_acc = jax.tree.map(
-                lambda a, g: a + g.astype(accum_dtype), grads_acc, grads)
+            with jax.named_scope("accumulate"):
+                grads_acc = jax.tree.map(
+                    lambda a, g: a + g.astype(accum_dtype), grads_acc, grads)
             return (loss_acc + loss.astype(jnp.float32), grads_acc), None
 
         zeros = jax.tree.map(
@@ -115,9 +116,10 @@ def accumulate_gradients(
         loss, grads = loss_and_grad(params, mb)
         # weight in f32, then cast: keeps the n_i/B factor exact and the
         # scan carry dtype stable when accum_dtype is bf16
-        grads_acc = jax.tree.map(
-            lambda a, g: a + (wgt * g).astype(accum_dtype),
-            grads_acc, grads)
+        with jax.named_scope("accumulate"):
+            grads_acc = jax.tree.map(
+                lambda a, g: a + (wgt * g).astype(accum_dtype),
+                grads_acc, grads)
         return (loss_acc + wgt * loss.astype(jnp.float32), grads_acc), None
 
     zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, accum_dtype), params)

@@ -61,13 +61,17 @@ def make_train_step(cfg: ArchConfig, tc: TrainConfig = TrainConfig()):
     metrics). Gradient accumulation is a ``lax.scan`` over micro-batches
     (the paper's mechanism; memory scales with batch/accum_steps)."""
 
+    def scoped_loss(params, micro_batch):
+        # the backward pass inherits the scope, under transpose(jvp(...))
+        with jax.named_scope("forward"):
+            return loss_fn(cfg, params, micro_batch,
+                           aux_loss_weight=tc.aux_loss_weight,
+                           remat=tc.remat, use_kernels=tc.use_kernels)
+
     def lg(params, micro_batch):
-        (loss, _), grads = jax.value_and_grad(
-            lambda p: loss_fn(cfg, p, micro_batch,
-                              aux_loss_weight=tc.aux_loss_weight,
-                              remat=tc.remat, use_kernels=tc.use_kernels),
-            has_aux=True)(params)
-        return loss, grads
+        (loss_value, _), grads = jax.value_and_grad(
+            scoped_loss, has_aux=True)(params, micro_batch)
+        return loss_value, grads
 
     def train_step(params, opt_state: OptState, batch):
         loss, grads = accumulate_gradients(
@@ -80,11 +84,13 @@ def make_train_step(cfg: ArchConfig, tc: TrainConfig = TrainConfig()):
             from repro.sharding.hooks import constrain_params_tree
             grads = constrain_params_tree(grads)
         lr = tc.schedule if tc.schedule is not None else tc.lr
-        params, opt_state = adamw_update(
-            grads, opt_state, params, lr=lr, weight_decay=tc.weight_decay)
-        gnorm = jnp.sqrt(sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in jax.tree.leaves(grads)))
+        with jax.named_scope("optimizer"):
+            params, opt_state = adamw_update(
+                grads, opt_state, params, lr=lr,
+                weight_decay=tc.weight_decay)
+            gnorm = jnp.sqrt(sum(
+                jnp.sum(jnp.square(g.astype(jnp.float32)))
+                for g in jax.tree.leaves(grads)))
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     return train_step
