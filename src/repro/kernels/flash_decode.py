@@ -22,6 +22,13 @@ accumulator.  GQA: the queries are grouped ``(B, groups, H_kv, ·)``
 groups reuses each page for all of its query heads — the kv heads are
 never repeated.
 
+The pool may be stacked over layers, ``(L, N_pages, page_size,
+H_kv*D)``, with the layer to read as a third scalar-prefetch operand: the
+decode step's layer scan carries the whole stacked pool, and the k/v
+index maps pick ``pool[layer, page]`` themselves, so no per-layer slab of
+the pool is ever sliced out (a custom call's operand is always
+materialised).  A 3-D pool is a stack of one, read at layer 0.
+
 Masking: the page table and the lengths ride in SMEM (scalar prefetch);
 positions ``>= length`` are masked.  Pages wholly past the length are
 neither fetched again nor computed; probabilities of masked rows inside
@@ -137,20 +144,28 @@ def _paged_body(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                                         keepdims=True).astype(o_ref.dtype)
 
 
-def flash_decode_paged(q, k_pool, v_pool, pages, lengths, *,
+def flash_decode_paged(q, k_pool, v_pool, pages, lengths, layer=0, *,
                        interpret=False):
-    """Paged flash decode. q: (B, 1, H, D); k_pool/v_pool:
-    (N_pages, page_size, H_kv*D) shared page pools (kv heads flattened
-    into the lane dim); pages: (B, P) i32 per-slot page table (-1 =
-    unassigned); lengths: (B,) valid rows.  Returns (B, 1, H, D).
+    """Paged flash decode. q: (B, 1, H, D); k_pool/v_pool: shared page
+    pools, (N_pages, page_size, H_kv*D) or stacked over layers as
+    (L, N_pages, page_size, H_kv*D) (kv heads flattened into the lane
+    dim); pages: (B, P) i32 per-slot page table (-1 = unassigned);
+    lengths: (B,) valid rows; layer: i32 scalar, the layer of a stacked
+    pool to read (0 for a 3-D pool).  Returns (B, 1, H, D).
 
-    Grid (B, P): one logical page per kv step.  The page table and the
-    lengths are scalar-prefetch SMEM operands — the table drives the k/v
-    index maps (which physical pool page to DMA next), so the indirection
-    costs nothing per step.  Pages past a slot's length map to its last
-    valid page, so their blocks are not fetched again, and the step is
-    skipped; unassigned entries (-1) are clamped to pool page 0."""
-    n_pg, page_size, width = k_pool.shape
+    Grid (B, P): one logical page per kv step.  The page table, the
+    lengths and the layer are scalar-prefetch SMEM operands, in that
+    order — the table and the layer drive the k/v index maps (which
+    physical pool page of which layer to DMA next), so the indirection
+    costs nothing per step.  The layer comes last so that the kernel's
+    first operands stay the table ``s32[B,P]`` and the lengths
+    ``s32[B]``, by which a device trace tells this kernel apart.  Pages
+    past a slot's length map to its last valid page, so their blocks are
+    not fetched again, and the step is skipped; unassigned entries (-1)
+    are clamped to pool page 0."""
+    if k_pool.ndim == 3:                       # one layer: a stack of one
+        k_pool, v_pool = k_pool[None], v_pool[None]
+    page_size, width = k_pool.shape[2:]
     b, one, h, d = q.shape
     assert one == 1 and v_pool.shape == k_pool.shape and width % d == 0, (
         q.shape, k_pool.shape, v_pool.shape)
@@ -161,30 +176,32 @@ def flash_decode_paged(q, k_pool, v_pool, pages, lengths, *,
     p_tab = pages.shape[1]
     assert pages.shape == (b, p_tab), (pages.shape, q.shape)
     pages_i = jnp.maximum(pages.astype(jnp.int32), 0)  # -1 -> page 0, masked
+    layer_i = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def kernel(pages_ref, len_ref, *refs):
-        del pages_ref
+    def kernel(pages_ref, len_ref, layer_ref, *refs):
+        del pages_ref, layer_ref
         _paged_body(len_ref, *refs, page_size=page_size, n_pages_tab=p_tab,
                     groups=groups, head_dim=d)
 
-    def kv_map(bi, ki, pr, lr):
+    def kv_map(bi, ki, pr, lr, yr):
         last = jnp.maximum(lr[bi] - 1, 0) // page_size
-        return (pr[bi, jnp.minimum(ki, last)], 0, 0)
+        return (yr[0], pr[bi, jnp.minimum(ki, last)], 0, 0)
 
+    kv_spec = pl.BlockSpec((None, 1, page_size, width), kv_map)
     out = pl.pallas_call(
         kernel,
         name="flash_decode_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, p_tab),
             in_specs=[
                 pl.BlockSpec((1, groups, h_kv, width),
-                             lambda bi, ki, pr, lr: (bi, 0, 0, 0)),
-                pl.BlockSpec((1, page_size, width), kv_map),
-                pl.BlockSpec((1, page_size, width), kv_map),
+                             lambda bi, ki, pr, lr, yr: (bi, 0, 0, 0)),
+                kv_spec,
+                kv_spec,
             ],
             out_specs=pl.BlockSpec((1, groups, width),
-                                   lambda bi, ki, pr, lr: (bi, 0, 0)),
+                                   lambda bi, ki, pr, lr, yr: (bi, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((groups, h_kv, 1), jnp.float32),      # max m
                 pltpu.VMEM((groups, h_kv, 1), jnp.float32),      # sum l
@@ -193,5 +210,5 @@ def flash_decode_paged(q, k_pool, v_pool, pages, lengths, *,
         ),
         out_shape=jax.ShapeDtypeStruct((b, groups, width), q.dtype),
         interpret=interpret,
-    )(pages_i, lengths.astype(jnp.int32), qblk, k_pool, v_pool)
+    )(pages_i, lengths.astype(jnp.int32), layer_i, qblk, k_pool, v_pool)
     return _ungroup(out.reshape(b, groups, h_kv, d), h)

@@ -156,27 +156,28 @@ def flash_decode(q, k, v, lengths, *, block_k=None):
                                 interpret=interpret)
 
 
-def flash_decode_paged(q, k_pool, v_pool, pages, lengths):
+def flash_decode_paged(q, k_pool, v_pool, pages, lengths, layer=0):
     """Paged decode attention. q: (B, 1, H, D) (model layout);
-    k_pool/v_pool: (N_pages, page_size, H_kv*D) shared pools; pages:
-    (B, P) per-slot page table (-1 = unassigned); lengths: (B,) valid
-    rows.  GQA is resolved inside the kernel — kv heads are never
-    repeated.  Not differentiable (serving only).
+    k_pool/v_pool: (N_pages, page_size, H_kv*D) shared pools, or
+    (L, N_pages, page_size, H_kv*D) stacked over layers and read at
+    ``layer``; pages: (B, P) per-slot page table (-1 = unassigned);
+    lengths: (B,) valid rows.  GQA is resolved inside the kernel — kv
+    heads are never repeated.  Not differentiable (serving only).
 
     The kernel has no block knobs, so tuned routing is consulted
     directly (``_resolve`` would early-return on the empty override
     set): an entry recording ``backend: "ref"`` for this
     (page_size, head_dim, dtype) class routes to the gather oracle,
     bitwise identical to the engine's jnp paged path."""
-    entry = autotune.lookup("flash_decode_paged", k_pool.shape[1],
+    entry = autotune.lookup("flash_decode_paged", k_pool.shape[-2],
                             q.shape[3], q.dtype)
     use_ref = entry is not None and entry.get("backend") == "ref"
     interpret = _route("flash_decode_paged", use_ref)
     if use_ref:
         return _ref.flash_decode_paged_ref(q, k_pool, v_pool, pages,
-                                           lengths)
+                                           lengths, layer)
     return _decode.flash_decode_paged(q, k_pool, v_pool, pages, lengths,
-                                      interpret=interpret)
+                                      layer, interpret=interpret)
 
 
 def ssd(x, dt, A, Bm, Cm, *, chunk=None):

@@ -53,24 +53,27 @@ def flash_decode_ref(q, k, v, lengths):
     return out.astype(q.dtype)
 
 
-def flash_decode_paged_ref(q, k_pool, v_pool, pages, lengths):
+def flash_decode_paged_ref(q, k_pool, v_pool, pages, lengths, layer=0):
     """Paged decode attention, XLA path — *model layout*.
 
     q: (B, 1, H, D); k_pool/v_pool: (N_pages, page_size, H_kv*D) shared
-    pools; pages: (B, P) block tables (-1 = unassigned); lengths: (B,)
-    valid rows.  Gathers each slot's pages into a linear cache (-1 rows
-    are gathered from page 0 but masked by ``lengths`` — the engine only
-    maps pages covering valid rows), repeats KV heads for GQA, and
-    defers to ``flash_decode_ref`` — so when the autotuner routes
-    ``ops.flash_decode_paged`` here the paged serving path stays BITWISE
-    identical to the engine's jnp path."""
+    pools, or (L, N_pages, page_size, H_kv*D) stacked over layers and
+    read at ``layer``; pages: (B, P) block tables (-1 = unassigned);
+    lengths: (B,) valid rows.  Gathers each slot's pages into a linear
+    cache (-1 rows are gathered from page 0 but masked by ``lengths`` —
+    the engine only maps pages covering valid rows), repeats KV heads for
+    GQA, and defers to ``flash_decode_ref`` — so when the autotuner
+    routes ``ops.flash_decode_paged`` here the paged serving path stays
+    BITWISE identical to the engine's jnp path."""
     b, p_tab = pages.shape
     h, d = q.shape[2], q.shape[3]
-    n_pages, ps, width = k_pool.shape
+    if k_pool.ndim == 3:                       # one layer: a stack of one
+        k_pool, v_pool = k_pool[None], v_pool[None]
+    ps, width = k_pool.shape[2:]
     h_kv = width // d
     safe = jnp.maximum(pages, 0)
-    k = k_pool[safe].reshape(b, p_tab * ps, h_kv, d)
-    v = v_pool[safe].reshape(b, p_tab * ps, h_kv, d)
+    k = k_pool[layer, safe].reshape(b, p_tab * ps, h_kv, d)
+    v = v_pool[layer, safe].reshape(b, p_tab * ps, h_kv, d)
     groups = h // h_kv
     if groups > 1:
         k = jnp.repeat(k, groups, axis=2)

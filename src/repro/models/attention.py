@@ -294,7 +294,7 @@ def attention_prefill_extend(p, x, cos, sin, cache, *, start, n_heads,
 
 def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
                      head_dim, window=0, use_kernel: bool = False,
-                     pages=None) -> Tuple[jnp.ndarray, dict]:
+                     pages=None, layer=0) -> Tuple[jnp.ndarray, dict]:
     """One-token decode. x: (B, 1, D); cache: {"k","v"} (B, S_cache, Hkv, D)
     ring-buffered when ``window > 0`` (S_cache == window), else linear
     (S_cache == max_len). ``index`` is the absolute decode position (B,)
@@ -306,7 +306,10 @@ def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
     cache k/v are shared pools ``(N_pages, page_size, Hkv*D)`` and
     ``pages`` is the per-example block table ``(B, P)`` mapping logical
     page ``index // page_size`` to a pool page (-1 = unassigned).  Linear
-    layout only (``window == 0``)."""
+    layout only (``window == 0``).  The pools may also be stacked over
+    layers, ``(L, N_pages, page_size, Hkv*D)``: ``layer`` (an i32 scalar,
+    traced inside the decode step's layer scan) picks the layer this call
+    writes and reads, and the whole stack is returned."""
     b, one, _ = x.shape
     assert one == 1
     q = linear(p["wq"], x).reshape(b, 1, n_heads, head_dim)
@@ -318,7 +321,7 @@ def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
     if pages is not None:
         assert window == 0, "paged KV requires the linear layout"
         return _attention_decode_paged(
-            p, q, k, v, cache, index, pages, n_heads=n_heads,
+            p, q, k, v, cache, index, pages, layer, n_heads=n_heads,
             n_kv_heads=n_kv_heads, head_dim=head_dim,
             use_kernel=use_kernel, out_dtype=x.dtype)
     s_cache = cache["k"].shape[1]
@@ -365,12 +368,20 @@ def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
     return linear(p["wo"], out), {"k": ck, "v": cv}
 
 
-def _attention_decode_paged(p, q, k, v, cache, index, pages, *, n_heads,
-                            n_kv_heads, head_dim, use_kernel, out_dtype
-                            ) -> Tuple[jnp.ndarray, dict]:
+def _attention_decode_paged(p, q, k, v, cache, index, pages, layer, *,
+                            n_heads, n_kv_heads, head_dim, use_kernel,
+                            out_dtype) -> Tuple[jnp.ndarray, dict]:
     """Paged one-token decode: write this step's K/V row into the pool
     page that owns position ``index``, then attend over the pages listed
     in the block table.
+
+    The pools are stacked over layers, ``(L, N_pages, page_size, ...)``,
+    and ``layer`` selects the one this call works on (a 3-D pool is a
+    stack of one, read at layer 0).  The row write is a scatter into the
+    stack at ``(layer, page, off)`` and the kernel reads ``pool[layer]``
+    through its index maps, so the decode step can carry the stacked
+    pool through its layer scan without ever slicing out or restacking
+    a layer's slab — both of which copy the slab.
 
     The jnp path gathers the table back into a ``(B, P*page_size, ...)``
     view — the same shape, row content, and masked-softmax reduction as
@@ -384,7 +395,11 @@ def _attention_decode_paged(p, q, k, v, cache, index, pages, *, n_heads,
     it a no-op.  A plain ``.at[-1]`` would *wrap* and corrupt the last
     pool page."""
     b = q.shape[0]
-    n_pg, page_size, _ = cache["k"].shape
+    ck, cv = cache["k"], cache["v"]
+    one_layer = ck.ndim == 3
+    if one_layer:
+        ck, cv, layer = ck[None], cv[None], 0
+    n_pg, page_size = ck.shape[1:3]
     p_tab = pages.shape[1]
     index = jnp.asarray(index)
     idx = index if index.ndim > 0 else jnp.broadcast_to(index[None], (b,))
@@ -395,10 +410,10 @@ def _attention_decode_paged(p, q, k, v, cache, index, pages, *, n_heads,
                     pages[ar, jnp.minimum(pidx, p_tab - 1)], -1)
     safe = jnp.where(pid >= 0, pid, n_pg)          # unassigned -> OOB drop
     with jax.named_scope("kv_write"):
-        ck = cache["k"].at[safe, off].set(
-            k[:, 0].reshape(b, -1).astype(cache["k"].dtype), mode="drop")
-        cv = cache["v"].at[safe, off].set(
-            v[:, 0].reshape(b, -1).astype(cache["v"].dtype), mode="drop")
+        ck = ck.at[layer, safe, off].set(
+            k[:, 0].reshape(b, -1).astype(ck.dtype), mode="drop")
+        cv = cv.at[layer, safe, off].set(
+            v[:, 0].reshape(b, -1).astype(cv.dtype), mode="drop")
     # no kv_cache constrain here: the pool layout (N_pages, ...) does not
     # match the (B, S, H, D) sharding rule, and serving runs single-host
 
@@ -407,13 +422,15 @@ def _attention_decode_paged(p, q, k, v, cache, index, pages, *, n_heads,
         from repro.kernels import flash_attention_ops
         lengths = idx + 1
         out = flash_attention_ops.flash_decode_paged(
-            q, ck, cv, pages, lengths)
+            q, ck, cv, pages, lengths, layer)
     else:
         # gather the table into the dense linear view; unassigned pages
         # read pool page 0 but every such position is masked below.
         gpid = jnp.maximum(pages, 0)               # (B, P)
-        gk = ck[gpid].reshape(b, p_tab * page_size, n_kv_heads, head_dim)
-        gv = cv[gpid].reshape(b, p_tab * page_size, n_kv_heads, head_dim)
+        gk = ck[layer, gpid].reshape(b, p_tab * page_size, n_kv_heads,
+                                     head_dim)
+        gv = cv[layer, gpid].reshape(b, p_tab * page_size, n_kv_heads,
+                                     head_dim)
         kk = _repeat_kv(gk, groups)
         vv = _repeat_kv(gv, groups)
         scale = head_dim ** -0.5
@@ -425,6 +442,8 @@ def _attention_decode_paged(p, q, k, v, cache, index, pages, *, n_heads,
         probs = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bhqk,bkhd->bqhd", probs, vv.astype(jnp.float32))
     out = out.astype(out_dtype).reshape(b, 1, n_heads * head_dim)
+    if one_layer:
+        ck, cv = ck[0], cv[0]
     return linear(p["wo"], out), {"k": ck, "v": cv}
 
 

@@ -766,13 +766,13 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
                head_dim=cfg.head_dim, window=win, use_kernel=use_kernels,
                pages=pages)
 
-    def unit_step(x, p, c):
+    def unit_step(x, p, c, li):
         new_c = c
         if fam in ("dense", "vlm"):
             with jax.named_scope("attention"):
                 h, kv = attn_mod.attention_decode(
                     p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps),
-                    cos, sin, c, idx, **akw)
+                    cos, sin, c, idx, layer=li, **akw)
                 x = x + h
             with jax.named_scope("mlp"):
                 x = x + swiglu(p["mlp"], rms_norm(p["ln2"], x, cfg.norm_eps))
@@ -784,7 +784,7 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
                 sub = p[f"sub{i}"]
                 h, kv = attn_mod.attention_decode(
                     sub["attn"], rms_norm(sub["ln1"], x, cfg.norm_eps),
-                    cos, sin, c[f"sub{i}"], idx, **akw)
+                    cos, sin, c[f"sub{i}"], idx, layer=li, **akw)
                 x = x + h
                 hn = rms_norm(sub["ln2"], x, cfg.norm_eps)
                 if i == u - 1:
@@ -811,7 +811,7 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
             if shared is not None:
                 h, kv = attn_mod.attention_decode(
                     shared["attn"], rms_norm(shared["ln1"], x, cfg.norm_eps),
-                    cos, sin, c["shared"], idx, **akw)
+                    cos, sin, c["shared"], idx, layer=li, **akw)
                 x = x + h
                 x = x + swiglu(shared["mlp"],
                                rms_norm(shared["ln2"], x, cfg.norm_eps))
@@ -839,7 +839,7 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
                 None, None, c["self"], idx,
                 n_heads=cfg.n_heads, n_kv_heads=cfg.n_heads,
                 head_dim=cfg.head_dim, window=0, use_kernel=use_kernels,
-                pages=pages)
+                pages=pages, layer=li)
             x = x + h
             xq = rms_norm(p["lnx"], x, cfg.norm_eps)
             h = _cross_decode(p["xattn"], cfg, xq, c["cross"],
@@ -851,12 +851,28 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
             raise ValueError(fam)
         return x, new_c
 
-    def body(x, pc):
+    # The paged pools ride in the layer scan's carry, stacked, and each
+    # layer writes and reads its own through the layer counter; the rest
+    # of the cache is sliced per layer as the scan's xs and ys.  A pool
+    # in xs/ys would be sliced out, restacked and copied back whole on
+    # every step.  With a block table, the pools are the 4-D leaves,
+    # (L, N_pages, page_size, Hkv*D): every other leaf is per slot, with
+    # a batch axis after its layer axes, so it has five dims or more.
+    is_pool = jax.tree.map(lambda t: pages is not None and t.ndim == 4,
+                           cache["units"])
+    pools, rest = _split_pools(cache["units"], is_pool)
+
+    def body(carry, pc):
+        x, pools, li = carry
         p, c = pc
-        return unit_step(x, p, c)
+        x, new_c = unit_step(x, p, _merge_pools(pools, c), li)
+        pools, rest = _split_pools(new_c, is_pool)
+        return (x, pools, li + 1), rest
 
     x = constrain(x, "act_btd")
-    x, new_units = jax.lax.scan(body, x, (params["units"], cache["units"]))
+    (x, pools, _), rest = jax.lax.scan(
+        body, (x, pools, jnp.zeros((), jnp.int32)), (params["units"], rest))
+    new_units = _merge_pools(pools, rest)
     with jax.named_scope("head"):
         x = rms_norm(params["ln_f"], x, cfg.norm_eps)
         logits = _lm_head(cfg, params, x)
@@ -864,6 +880,18 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
     new_cache["units"] = new_units
     new_cache["index"] = idx + 1
     return constrain(logits, "logits"), new_cache
+
+
+def _split_pools(units, is_pool):
+    """(pools, rest): ``units`` twice, each tree holding ``None`` where
+    the other holds a leaf, as the boolean tree ``is_pool`` says."""
+    return (jax.tree.map(lambda t, pl: t if pl else None, units, is_pool),
+            jax.tree.map(lambda t, pl: None if pl else t, units, is_pool))
+
+
+def _merge_pools(pools, rest):
+    return jax.tree.map(lambda a, b: b if a is None else a, pools, rest,
+                        is_leaf=lambda t: t is None)
 
 
 def _cross_attention_cached(p, cfg, xq, cross, cross_len):

@@ -12,7 +12,8 @@ from repro.configs import get_config
 from repro.kernels import ops
 from repro.launch import serve
 from repro.launch.engine import DecodeEngine
-from repro.models import init_paged_cache, init_params
+from repro.models import (decode_step, init_cache, init_paged_cache,
+                          init_params)
 from repro.models.attention import attention_decode, attention_init
 
 # every family with a linear KV cache (the ones paging applies to)
@@ -59,6 +60,42 @@ class TestFlashDecodePagedKernel:
         ref = flash_decode_ref(q, rep(gk), rep(gv), lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5)
+
+
+    @pytest.mark.parametrize("h,hkv", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+    def test_stacked_pool_matches_gather_oracle_every_layer(self, h, hkv):
+        """An (L, N, PS, W) pool with different contents per layer: read
+        at a traced layer index, the kernel matches the gather oracle on
+        ``pool[l]`` for every ``l``, and equals the 3-D call on that
+        layer's pool."""
+        rng = np.random.default_rng(2)
+        b, d, ps, n_pg, p_tab, n_layers = 3, 16, 8, 11, 4, 3
+        q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
+        pool_k, pool_v = (jnp.asarray(rng.standard_normal(
+            (n_layers, n_pg, ps, hkv * d)), jnp.float32) for _ in range(2))
+        lengths = jnp.asarray([5, 17, 32], jnp.int32)
+        pages = np.full((b, p_tab), -1, np.int32)
+        free = list(range(n_pg))
+        for bi in range(b):
+            for pi in range(-(-int(lengths[bi]) // ps)):
+                pages[bi, pi] = free.pop()
+        pages = jnp.asarray(pages)
+        stacked = jax.jit(ops.flash_decode_paged)
+        from repro.kernels.ref import flash_decode_ref
+        rep = lambda t: jnp.repeat(t, h // hkv, axis=2)   # noqa: E731
+        for layer in range(n_layers):
+            out = stacked(q, pool_k, pool_v, pages, lengths,
+                          jnp.int32(layer))
+            gk = pool_k[layer, jnp.maximum(pages, 0)].reshape(
+                b, p_tab * ps, hkv, d)
+            gv = pool_v[layer, jnp.maximum(pages, 0)].reshape(
+                b, p_tab * ps, hkv, d)
+            ref = flash_decode_ref(q, rep(gk), rep(gv), lengths)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                       atol=1e-5, rtol=1e-5)
+            flat = ops.flash_decode_paged(q, pool_k[layer], pool_v[layer],
+                                          pages, lengths)
+            assert (np.asarray(out) == np.asarray(flat)).all()
 
 
 # ====================================================================== #
@@ -131,6 +168,81 @@ class TestAttentionDecodePaged:
                                       pages=blank, **kw)
         assert (np.asarray(cache_p["k"]) == np.asarray(paged["k"])).all()
         assert (np.asarray(cache_p["v"]) == np.asarray(paged["v"])).all()
+
+
+# ====================================================================== #
+# decode_step over a stacked pool: each layer writes its own row
+# ====================================================================== #
+class TestDecodeStepPagedWrites:
+    """The decode step carries the pools, stacked over layers, through
+    its layer scan; layer ``l`` must write row ``(l, owning page, off)``
+    and nothing else."""
+    N_PG, PS, MAX_LEN = 12, 8, 32
+
+    def _setup(self):
+        cfg = dataclasses.replace(_cfg("minicpm-2b"), n_layers=3)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        rng = np.random.default_rng(3)
+        b, p_tab = 3, self.MAX_LEN // self.PS
+        idx = np.asarray([3, 11, 30])
+        cache = init_paged_cache(cfg, b, self.MAX_LEN, page_size=self.PS,
+                                 n_pages=self.N_PG)
+        shape = cache["units"]["k"].shape             # (L, N, PS, W)
+        cache["units"] = {n: jnp.asarray(rng.standard_normal(shape),
+                                         jnp.float32) for n in ("k", "v")}
+        # slots 0 and 1 own the pages that hold their index; slot 2's
+        # table stops a page short of position 30, so its write drops
+        pages = np.full((b, p_tab), -1, np.int32)
+        free = list(range(self.N_PG))
+        for bi, n in enumerate((1, 2, 3)):
+            for pi in range(n):
+                pages[bi, pi] = free.pop()
+        cache["pages"] = jnp.asarray(pages)
+        cache["index"] = jnp.asarray(idx, jnp.int32)
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab, (b, 1)), jnp.int32)
+        return cfg, params, cache, tokens, idx, pages
+
+    @pytest.mark.parametrize("use_kernels", [False, True],
+                             ids=["jnp", "kernel"])
+    def test_write_lands_in_owning_page_every_layer(self, use_kernels):
+        cfg, params, cache, tokens, idx, pages = self._setup()
+        _, new = decode_step(cfg, params, cache, tokens,
+                             use_kernels=use_kernels)
+        # the dense cache holding the same rows gives the expected ones
+        dense = init_cache(cfg, 3, self.MAX_LEN)
+        gpid = jnp.maximum(jnp.asarray(pages), 0)
+        dense["units"] = {
+            n: cache["units"][n][:, gpid].reshape(dense["units"][n].shape)
+            for n in ("k", "v")}
+        _, want = decode_step(cfg, params, dense, tokens,
+                              index=jnp.asarray(idx, jnp.int32))
+        for n in ("k", "v"):
+            old = np.asarray(cache["units"][n])
+            got = np.asarray(new["units"][n])
+            changed = np.zeros(old.shape[:3], bool)
+            for bi in (0, 1):
+                pg, off = pages[bi, idx[bi] // self.PS], idx[bi] % self.PS
+                changed[:, pg, off] = True
+                row = np.asarray(want["units"][n])[:, bi, idx[bi]]
+                row = row.reshape(cfg.n_units, -1)
+                if use_kernels:
+                    np.testing.assert_allclose(got[:, pg, off], row,
+                                               atol=1e-5, rtol=1e-5)
+                else:
+                    assert (got[:, pg, off] == row).all()
+            assert (got[~changed] == old[~changed]).all()
+            assert not (got[changed] == old[changed]).all(axis=-1).any()
+
+    @pytest.mark.parametrize("use_kernels", [False, True],
+                             ids=["jnp", "kernel"])
+    def test_unassigned_page_write_drops_every_layer(self, use_kernels):
+        cfg, params, cache, tokens, _, pages = self._setup()
+        cache["pages"] = jnp.full_like(cache["pages"], -1)
+        _, new = decode_step(cfg, params, cache, tokens,
+                             use_kernels=use_kernels)
+        for n in ("k", "v"):
+            assert (np.asarray(new["units"][n])
+                    == np.asarray(cache["units"][n])).all()
 
 
 # ====================================================================== #

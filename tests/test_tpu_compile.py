@@ -10,7 +10,11 @@ check that a Mosaic kernel (``tpu_custom_call``) is in the program:
   S = 1024, 2048, 4096, and its suffix-prefill forward;
 * ``flash_decode`` and ``flash_decode_paged`` at minicpm-2b serving
   widths (8 slots, 2048-row cache, 16-row pages), and the paged kernel
-  at qwen2-vl-2b's GQA widths (12 query heads over 2 kv heads x 128);
+  at qwen2-vl-2b's GQA widths (12 query heads over 2 kv heads x 128),
+  each over one layer's pool and over a pool stacked over layers;
+* the paged engine's decode segment at minicpm-2b widths (4 layers),
+  whose compiled program must write the stacked KV pool in place:
+  no per-layer slab of it is sliced out, restacked or copied;
 * the SSD scan fwd+bwd at zamba2-7b widths (112 heads x 64, state 64).
 
 The topology is described inside a module fixture (never at import: the
@@ -18,8 +22,10 @@ TPU library admits one process at a time), and the persistent
 compilation cache is off for the whole file, since an entry written
 without a chip cannot be read back.
 """
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -85,14 +91,81 @@ def test_flash_decode(one_chip):
              ((8, 2048, 36, 64), BF16), ((8,), I32))
 
 
-@pytest.mark.parametrize("h,h_kv,d,n_pages", [
-    (36, 36, 64, 512),          # minicpm-2b (MHA)
-    (12, 2, 128, 512),          # qwen2-vl-2b (GQA, 6 query heads per kv)
+@pytest.mark.parametrize("h,h_kv,d,n_pages,layers", [
+    pytest.param(36, 36, 64, 512, 0, id="36-36-64-512"),  # minicpm-2b MHA
+    pytest.param(12, 2, 128, 512, 0, id="12-2-128-512"),  # qwen2-vl GQA 6:1
+    pytest.param(36, 36, 64, 512, 4, id="36-36-64-512-stacked"),
+    pytest.param(12, 2, 128, 512, 4, id="12-2-128-512-stacked"),
 ])
-def test_flash_decode_paged(one_chip, h, h_kv, d, n_pages):
-    pool = ((n_pages, 16, h_kv * d), BF16)
+def test_flash_decode_paged(one_chip, h, h_kv, d, n_pages, layers):
+    """``layers`` 0: one layer's (N, PS, W) pool; otherwise an
+    (L, N, PS, W) pool read at a traced layer index."""
+    if not layers:
+        pool = ((n_pages, 16, h_kv * d), BF16)
+        _compile(_decode.flash_decode_paged, one_chip,
+                 ((8, 1, h, d), BF16), pool, pool, ((8, 128), I32),
+                 ((8,), I32))
+        return
+    pool = ((layers, n_pages, 16, h_kv * d), BF16)
     _compile(_decode.flash_decode_paged, one_chip,
-             ((8, 1, h, d), BF16), pool, pool, ((8, 128), I32), ((8,), I32))
+             ((8, 1, h, d), BF16), pool, pool, ((8, 128), I32), ((8,), I32),
+             ((), I32))
+
+
+def _results(text, shape):
+    """(name, opcode) of every instruction whose result is ``shape``."""
+    pat = re.compile(r"^\s*(?:ROOT )?%(\S+) = " + re.escape(shape)
+                     + r"\{[^}]*\} ([\w-]+)\(", re.M)
+    return pat.findall(text)
+
+
+def test_decode_segment_writes_pool_in_place(one_chip, monkeypatch):
+    """The paged engine's 16-step decode segment at minicpm-2b widths
+    with 4 layers, 8 slots of 2048 positions over a 512 x 16 pool,
+    kernels on, the cache donated.  The pool rides in the layer scan's
+    carry and the paged kernel reads it through a layer index, so the
+    program holds no per-layer slab of the pool, and nothing copies,
+    slices or restacks the stacked pool; the only pool-sized results
+    are the in-place row scatters (and the loop plumbing)."""
+    from types import SimpleNamespace
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch.engine import DecodeEngine
+    from repro.models import init_paged_cache, init_params
+
+    monkeypatch.setattr(ops, "_route", lambda kind, use_ref=False: False)
+    cfg = dataclasses.replace(get_config("minicpm-2b"), n_layers=4)
+    seg = DecodeEngine._make_segment_fn(
+        SimpleNamespace(cfg=cfg, segment=16, use_kernels=True))
+
+    def cache():
+        c = init_paged_cache(cfg, 8, 2048, page_size=16, n_pages=512)
+        c["index"] = jnp.zeros((8,), I32)
+        return c
+
+    def spec(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=one_chip), tree)
+
+    params = spec(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    text = jax.jit(seg, donate_argnums=(1,)).lower(
+        params, spec(jax.eval_shape(cache)),
+        jax.ShapeDtypeStruct((8, 1), I32, sharding=one_chip)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+
+    slab = (_results(text, "bf16[512,16,2304]")
+            + _results(text, "bf16[1,512,16,2304]"))
+    assert not slab, slab
+    stacked = _results(text, "bf16[4,512,16,2304]")
+    moved = [(name, op) for name, op in stacked
+             if op in ("copy", "dynamic-slice", "dynamic-update-slice",
+                       "custom-call")          # custom-call: AllocateBuffer
+             or (op == "fusion" and re.search(r"copy|dynamic", name))]
+    assert not moved, moved
+    assert any(op == "scatter" for _, op in stacked), stacked
 
 
 def test_ssd_fwd_bwd(one_chip):
