@@ -42,6 +42,10 @@ class ArchConfig:
     ssm_expand: int = 2
     attn_every: int = 0             # zamba2: shared attn block every k layers
     slstm_every: int = 0            # xlstm: sLSTM block every k layers
+    # hybrid with per-layer mixers (granite-4.0-h): the published layer
+    # types of one period, "mamba" or "attention", each mixer followed
+    # by its own MLP; empty -> zamba's shared block every ``attn_every``
+    layer_pattern: Tuple[str, ...] = ()
 
     # --- positions / attention variants ---------------------------------
     rope: bool = True
@@ -57,6 +61,12 @@ class ArchConfig:
 
     # --- VLM stub --------------------------------------------------------
     vision_tokens: int = 0          # prefix length of stubbed patch embeds
+
+    # --- scalar multipliers (granite); the defaults are the identity ------
+    embedding_multiplier: float = 1.0   # x0 = embed(tokens) * m
+    residual_multiplier: float = 1.0    # x = x + m * block(norm(x))
+    attention_multiplier: float = 0.0   # softmax scale; 0 -> head_dim**-0.5
+    logits_scaling: float = 1.0         # logits = head(x) / s
 
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -80,6 +90,14 @@ class ArchConfig:
         return self.family in ("ssm", "hybrid") or self.sliding_window > 0
 
     @property
+    def q_scale(self) -> float:
+        """Query multiplier that turns the attention's head_dim**-0.5 into
+        the model's own softmax scale (1.0 where it has none)."""
+        if not self.attention_multiplier:
+            return 1.0
+        return self.attention_multiplier * self.head_dim ** 0.5
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -93,6 +111,8 @@ class ArchConfig:
         are grouped into repeating units)."""
         if self.family == "moe" and self.moe_every > 1:
             return self.moe_every
+        if self.family == "hybrid" and self.layer_pattern:
+            return len(self.layer_pattern)
         if self.family == "hybrid" and self.attn_every > 0:
             return self.attn_every
         if self.slstm_every > 0:
@@ -141,6 +161,11 @@ class ArchConfig:
                     total += 4 * d * d + 2 * d      # sLSTM-ish
                 else:
                     total += self._mamba_params() + 2 * d
+            elif self.family == "hybrid" and self.layer_pattern:
+                kind = self.layer_pattern[i % len(self.layer_pattern)]
+                total += (attn_p if kind == "attention"
+                          else self._mamba_params()) \
+                    + 3 * d * self.d_ff + 2 * d
             elif self.family == "hybrid":
                 total += self._mamba_params() + 2 * d
         if self.family == "hybrid" and self.attn_every:
@@ -214,6 +239,7 @@ class ArchConfig:
 # ---------------------------------------------------------------------- #
 _REGISTRY: Dict[str, str] = {
     "zamba2-7b": "repro.configs.zamba2_7b",
+    "granite-4.0-h-micro": "repro.configs.granite_4_0_h_micro",
     "minicpm-2b": "repro.configs.minicpm_2b",
     "glm4-9b": "repro.configs.glm4_9b",
     "llama4-maverick-400b-a17b": "repro.configs.llama4_maverick",

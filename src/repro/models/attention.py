@@ -163,11 +163,14 @@ def chunked_attention(q, k, v, *, causal=True, window=0,
 # module-level forward
 # ---------------------------------------------------------------------- #
 def attention(p, x, cos, sin, *, n_heads, n_kv_heads, head_dim,
-              causal=True, window=0, use_kernel: bool = False
-              ) -> jnp.ndarray:
+              causal=True, window=0, use_kernel: bool = False,
+              q_scale: float = 1.0) -> jnp.ndarray:
     """Training / prefill attention over the whole sequence.
 
-    cos/sin: RoPE tables (may be None for NoPE/xLSTM-style blocks)."""
+    cos/sin: RoPE tables (may be None for NoPE/xLSTM-style blocks).
+    ``q_scale`` multiplies the queries before the head_dim**-0.5 of the
+    softmax, so a model's own softmax scale ``a`` is ``q_scale =
+    a * sqrt(head_dim)`` (1.0 leaves the program as it was)."""
     b, s, _ = x.shape
     q = linear(p["wq"], x).reshape(b, s, n_heads, head_dim)
     k = linear(p["wk"], x).reshape(b, s, n_kv_heads, head_dim)
@@ -175,6 +178,8 @@ def attention(p, x, cos, sin, *, n_heads, n_kv_heads, head_dim,
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+    if q_scale != 1.0:
+        q = q * jnp.asarray(q_scale, q.dtype)
     q = constrain(q, "act_heads")
     groups = n_heads // n_kv_heads
     k = _repeat_kv(k, groups)
@@ -194,8 +199,8 @@ def attention(p, x, cos, sin, *, n_heads, n_kv_heads, head_dim,
 
 
 def attention_prefill(p, x, cos, sin, cache, *, n_heads, n_kv_heads,
-                      head_dim, window=0, use_kernel: bool = False
-                      ) -> Tuple[jnp.ndarray, dict]:
+                      head_dim, window=0, use_kernel: bool = False,
+                      q_scale: float = 1.0) -> Tuple[jnp.ndarray, dict]:
     """Single-shot prefill: attend over the whole prompt (same math as
     ``attention``) AND write the per-position K/V rows into a FRESH decode
     cache.  x: (B, S, D); cache: {"k","v"} (B, S_cache, Hkv, D) — linear
@@ -209,6 +214,8 @@ def attention_prefill(p, x, cos, sin, cache, *, n_heads, n_kv_heads,
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+    if q_scale != 1.0:
+        q = q * jnp.asarray(q_scale, q.dtype)
     q = constrain(q, "act_heads")
     s_cache = cache["k"].shape[1]
     if window > 0:
@@ -294,7 +301,8 @@ def attention_prefill_extend(p, x, cos, sin, cache, *, start, n_heads,
 
 def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
                      head_dim, window=0, use_kernel: bool = False,
-                     pages=None, layer=0) -> Tuple[jnp.ndarray, dict]:
+                     pages=None, layer=0, q_scale: float = 1.0
+                     ) -> Tuple[jnp.ndarray, dict]:
     """One-token decode. x: (B, 1, D); cache: {"k","v"} (B, S_cache, Hkv, D)
     ring-buffered when ``window > 0`` (S_cache == window), else linear
     (S_cache == max_len). ``index`` is the absolute decode position (B,)
@@ -318,6 +326,8 @@ def attention_decode(p, x, cos, sin, cache, index, *, n_heads, n_kv_heads,
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+    if q_scale != 1.0:
+        q = q * jnp.asarray(q_scale, q.dtype)
     if pages is not None:
         assert window == 0, "paged KV requires the linear layout"
         return _attention_decode_paged(

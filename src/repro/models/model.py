@@ -75,6 +75,22 @@ def _moe_layer_init(key, cfg: ArchConfig, dtype):
     }
 
 
+def _hybrid_layer_init(key, cfg: ArchConfig, kind: str, dtype):
+    """One layer of a hybrid with per-layer mixers: its mixer (Mamba2 or
+    attention) and the MLP after it, each behind its own norm."""
+    if kind == "attention":
+        return _attn_block_init(key, cfg, dtype)
+    k1, k2 = jax.random.split(key)
+    return {
+        "ln1": rms_norm_init(cfg.d_model, dtype),
+        "mamba": ssm_mod.mamba2_init(
+            k1, cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads,
+            cfg.ssm_conv, dtype=dtype),
+        "ln2": rms_norm_init(cfg.d_model, dtype),
+        "mlp": swiglu_init(k2, cfg.d_model, cfg.d_ff, dtype=dtype),
+    }
+
+
 def _unit_init(key, cfg: ArchConfig, dtype):
     fam = cfg.family
     if fam in ("dense", "vlm"):
@@ -90,6 +106,10 @@ def _unit_init(key, cfg: ArchConfig, dtype):
             else:
                 unit[f"sub{i}"] = _attn_block_init(keys[i], cfg, dtype)
         return unit
+    if fam == "hybrid" and cfg.layer_pattern:   # granite: layer by layer
+        keys = jax.random.split(key, len(cfg.layer_pattern))
+        return {f"sub{i}": _hybrid_layer_init(keys[i], cfg, kind, dtype)
+                for i, kind in enumerate(cfg.layer_pattern)}
     if fam == "hybrid":            # zamba2: u mamba layers (+ shared attn)
         u = cfg.pattern_unit()
 
@@ -231,11 +251,60 @@ def _moe_layer_fwd(p, cfg, x, cos, sin, window, use_kernels):
     return constrain(x + y, "act_btd"), aux
 
 
+def _scaled(x, m: float):
+    """``x * m`` computed in float32 (a multiplier such as 0.22 is no bf16
+    number); ``x`` itself where ``m`` is 1."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
+def _hybrid_layer(cfg: ArchConfig, kind: str, p, x, mix, state=None):
+    """One layer of a hybrid with per-layer mixers (granite): ``x + r *
+    mixer(norm(x))``, then ``+ r * MLP(norm(.))``, r the residual
+    multiplier.  ``mix(kind, mixer params, normed x, state)`` returns the
+    mixer's output and its new decode state (None in training).  The
+    mixer runs under the scope ``kind`` ("mamba" or "attention")."""
+    with jax.named_scope(kind):
+        h, state = mix(kind, p["attn" if kind == "attention" else "mamba"],
+                       rms_norm(p["ln1"], x, cfg.norm_eps), state)
+        x = x + _scaled(h, cfg.residual_multiplier)
+    with jax.named_scope("mlp"):
+        h = swiglu(p["mlp"], rms_norm(p["ln2"], x, cfg.norm_eps))
+        x = x + _scaled(h, cfg.residual_multiplier)
+    return constrain(x, "act_btd"), state
+
+
 def _make_unit_fwd(cfg: ArchConfig, shared_attn, cos, sin, window,
-                   use_kernels):
+                   use_kernels, remat: bool = False):
+    """The forward of one unit.  ``remat`` recomputes each layer of a
+    per-layer hybrid in the backward (its unit is then not
+    checkpointed whole, see ``forward``)."""
     fam = cfg.family
 
-    if fam in ("dense", "vlm"):
+    if fam == "hybrid" and cfg.layer_pattern:
+        def mix(kind, mp, h, _):
+            if kind == "attention":
+                return attn_mod.attention(
+                    mp, h, cos, sin, n_heads=cfg.n_heads,
+                    n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+                    window=window, use_kernel=use_kernels,
+                    q_scale=cfg.q_scale), None
+            return ssm_mod.mamba2_forward(
+                mp, h, d_inner=cfg.d_inner, ssm_state=cfg.ssm_state,
+                n_heads=cfg.n_ssm_heads, use_kernel=use_kernels), None
+
+        def layer_fn(kind):
+            def layer(x, lp):
+                return _hybrid_layer(cfg, kind, lp, x, mix)[0]
+            return jax.checkpoint(layer) if remat else layer
+        layer = {k: layer_fn(k) for k in dict.fromkeys(cfg.layer_pattern)}
+
+        def unit_fwd(x, p):
+            for i, kind in enumerate(cfg.layer_pattern):
+                x = layer[kind](x, p[f"sub{i}"])
+            return x, jnp.zeros(())
+    elif fam in ("dense", "vlm"):
         def unit_fwd(x, p):
             return _attn_block_fwd(p, cfg, x, cos, sin, window,
                                    use_kernels), jnp.zeros(())
@@ -310,7 +379,7 @@ def forward(cfg: ArchConfig, params, batch: Dict[str, jnp.ndarray], *,
     tokens = batch["tokens"]
     b, s = tokens.shape
     dt = _dtype(cfg)
-    x = embed(params["embed"], tokens, dt)
+    x = _scaled(embed(params["embed"], tokens, dt), cfg.embedding_multiplier)
     if cfg.family == "vlm" and "vision_embeds" in batch:
         tv = batch["vision_embeds"].shape[1]
         x = jnp.concatenate(
@@ -326,9 +395,11 @@ def forward(cfg: ArchConfig, params, batch: Dict[str, jnp.ndarray], *,
     if cfg.family == "ssm" and not cfg.rope:
         cos = sin = None
     shared = params.get("shared_attn")
+    per_layer = bool(cfg.layer_pattern)      # recomputed layer by layer
     unit_fwd = _make_unit_fwd(cfg, shared, cos, sin, cfg.sliding_window,
-                              use_kernels)
-    x, aux = _scan_units(x, params["units"], unit_fwd, remat)
+                              use_kernels, remat=remat and per_layer)
+    x, aux = _scan_units(x, params["units"], unit_fwd,
+                         remat and not per_layer)
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
     logits = _lm_head(cfg, params, x)
     return constrain(logits, "logits"), aux
@@ -336,8 +407,10 @@ def forward(cfg: ArchConfig, params, batch: Dict[str, jnp.ndarray], *,
 
 def _lm_head(cfg, params, x):
     if cfg.tie_embeddings:
-        return unembed(params["embed"], x)
-    return linear(params["lm_head"], x)
+        logits = unembed(params["embed"], x)
+    else:
+        logits = linear(params["lm_head"], x)
+    return _scaled(logits, 1.0 / cfg.logits_scaling)
 
 
 # ---------------------------------------------------------------------- #
@@ -420,6 +493,12 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
         if fam == "moe":
             return {f"sub{i}": kv(cfg.n_kv_heads)
                     for i in range(cfg.pattern_unit())}
+        if fam == "hybrid" and cfg.layer_pattern:
+            m = ssm_mod.mamba2_init_cache(
+                batch, cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads,
+                cfg.ssm_conv, dt)
+            return {f"sub{i}": kv(cfg.n_kv_heads) if kind == "attention"
+                    else m for i, kind in enumerate(cfg.layer_pattern)}
         if fam == "hybrid":
             u = cfg.pattern_unit()
             m = ssm_mod.mamba2_init_cache(
@@ -485,6 +564,10 @@ def init_paged_cache(cfg: ArchConfig, batch: int, max_len: int, *,
     elif fam == "moe":
         unit = {f"sub{i}": paged_kv(cfg.n_kv_heads)
                 for i in range(cfg.pattern_unit())}
+    elif fam == "hybrid" and cfg.layer_pattern:
+        raise ValueError("paged KV for the attention layers of a per-layer "
+                         "hybrid beside its per-slot SSM state is not "
+                         "implemented")
     elif fam == "hybrid":
         u = cfg.pattern_unit()
         m = ssm_mod.mamba2_init_cache(
@@ -551,7 +634,7 @@ def prefill(cfg: ArchConfig, params, cache, tokens, *,
     b, s = tokens.shape
     fam = cfg.family
     win = cfg.sliding_window
-    x = embed(params["embed"], tokens, dt)
+    x = _scaled(embed(params["embed"], tokens, dt), cfg.embedding_multiplier)
     cos = sin = None
     if cfg.is_encoder_decoder:
         pos = sinusoidal_positions(jnp.arange(s), cfg.d_model).astype(dt)
@@ -563,9 +646,22 @@ def prefill(cfg: ArchConfig, params, cache, tokens, *,
     akw = dict(n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
                head_dim=cfg.head_dim, window=win, use_kernel=use_kernels)
 
+    def mix(kind, mp, h, lc):
+        if kind == "attention":
+            return attn_mod.attention_prefill(mp, h, cos, sin, lc, **akw,
+                                              q_scale=cfg.q_scale)
+        return ssm_mod.mamba2_prefill(
+            mp, h, lc, d_inner=cfg.d_inner, ssm_state=cfg.ssm_state,
+            n_heads=cfg.n_ssm_heads)
+
     def unit_prefill(x, p, c):
         new_c = c
-        if fam in ("dense", "vlm"):
+        if fam == "hybrid" and cfg.layer_pattern:
+            new_c = dict(c)
+            for i, kind in enumerate(cfg.layer_pattern):
+                x, new_c[f"sub{i}"] = _hybrid_layer(
+                    cfg, kind, p[f"sub{i}"], x, mix, c[f"sub{i}"])
+        elif fam in ("dense", "vlm"):
             h, kv = attn_mod.attention_prefill(
                 p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps),
                 cos, sin, c, **akw)
@@ -745,7 +841,7 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
     dt = _dtype(cfg)
     b = tokens.shape[0]
     idx = cache["index"] if index is None else jnp.asarray(index)
-    x = embed(params["embed"], tokens, dt)
+    x = _scaled(embed(params["embed"], tokens, dt), cfg.embedding_multiplier)
     fam = cfg.family
     win = cfg.sliding_window
 
@@ -768,7 +864,20 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, *,
 
     def unit_step(x, p, c, li):
         new_c = c
-        if fam in ("dense", "vlm"):
+        if fam == "hybrid" and cfg.layer_pattern:
+            def mix(kind, mp, h, lc):
+                if kind == "attention":
+                    return attn_mod.attention_decode(
+                        mp, h, cos, sin, lc, idx, layer=li,
+                        q_scale=cfg.q_scale, **akw)
+                return ssm_mod.mamba2_decode(
+                    mp, h, lc, d_inner=cfg.d_inner,
+                    ssm_state=cfg.ssm_state, n_heads=cfg.n_ssm_heads)
+            new_c = dict(c)
+            for i, kind in enumerate(cfg.layer_pattern):
+                x, new_c[f"sub{i}"] = _hybrid_layer(
+                    cfg, kind, p[f"sub{i}"], x, mix, c[f"sub{i}"])
+        elif fam in ("dense", "vlm"):
             with jax.named_scope("attention"):
                 h, kv = attn_mod.attention_decode(
                     p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps),

@@ -15,7 +15,12 @@ check that a Mosaic kernel (``tpu_custom_call``) is in the program:
 * the paged engine's decode segment at minicpm-2b widths (4 layers),
   whose compiled program must write the stacked KV pool in place:
   no per-layer slab of it is sliced out, restacked or copied;
-* the SSD scan fwd+bwd at zamba2-7b widths (112 heads x 64, state 64).
+* the SSD scan fwd+bwd at zamba2-7b widths (112 heads x 64, state 64)
+  and at granite-4.0-h-micro's (64 heads x 64, state 128, float32
+  operands as the model passes them);
+* granite-4.0-h-micro's fused training step at 10 layers (one period),
+  batch 2 x 2048, kernels on and state donated: the SSD and flash
+  kernels are in it, and it fits a v5e's memory.
 
 The topology is described inside a module fixture (never at import: the
 TPU library admits one process at a time), and the persistent
@@ -178,3 +183,48 @@ def test_ssd_fwd_bwd(one_chip):
 
     _compile(fwd_bwd, one_chip, ((b, s, h, p), BF16), ((b, s, h), BF16),
              ((h,), F32), ((b, s, n), BF16), ((b, s, n), BF16))
+
+
+def test_ssd_fwd_bwd_granite(one_chip):
+    b, s, h, p, n = 2, 2048, 64, 64, 128
+
+    def fwd_bwd(x, dt, A, Bm, Cm):
+        def loss(*a):
+            return _ssd.ssd(*a).sum()
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
+
+    _compile(fwd_bwd, one_chip, ((b, s, h, p), F32), ((b, s, h), F32),
+             ((h,), F32), ((b, s, n), F32), ((b, s, n), F32))
+
+
+def test_hybrid_group_step(one_chip, monkeypatch):
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch.cluster import JobSpec, make_group_step
+    from repro.models import init_params
+    from repro.train import adamw_init
+
+    monkeypatch.setattr(ops, "_route", lambda kind, use_ref=False: False)
+    cfg = dataclasses.replace(get_config("granite-4.0-h-micro"), n_layers=10)
+    step = make_group_step([JobSpec(cfg, batch=2, seq=2048,
+                                    use_kernels=True)], donate=True)
+
+    def spec(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=one_chip), tree)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((2, 2048), I32, sharding=one_chip)
+    compiled = step.lower(spec(params), spec(jax.eval_shape(
+        lambda: adamw_init(params))), {"tokens": tokens, "labels": tokens}
+    ).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"^\s*(?:ROOT )?%(\w+?)(?:\.\d+)? = .*"
+                         r'custom_call_target="tpu_custom_call"', text, re.M)
+    # nine Mamba2 layers: a forward, its recompute and a backward each
+    assert kernels.count("ssd_fwd") == 18 and kernels.count("ssd_bwd") == 9
+    assert {"flash_attention_fwd", "flash_attention_dq",
+            "flash_attention_dkv"} <= set(kernels)
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert peak < 15e9, peak
