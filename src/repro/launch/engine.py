@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_decode import kv_grid
 from repro.launch.cluster import ScriptedFaults, TransientFault
 from repro.launch.prefix import PrefixTrie
 from repro.launch.serve import _make_scan_generate, prefill_extend_cached
@@ -132,6 +133,16 @@ class DecodeEngine:
             self._is_pool = jax.tree.map(
                 lambda pg, dn: pg.shape != dn.shape,
                 cache["units"], dense_shapes)
+            # the paged kernel's grid: per decode step, one call per
+            # attention layer (a k and a v pool each), steps per slot each
+            pools = [t for t, pl in zip(jax.tree.leaves(cache["units"]),
+                                        jax.tree.leaves(self._is_pool))
+                     if pl]
+            self._kv_layers = sum(t.shape[0] for t in pools) // 2
+            n, self._kv_steps = kv_grid(page_size, pools[0].shape[-1],
+                                        max_len // page_size,
+                                        pools[0].dtype.itemsize)
+            self._kv_rows = n * page_size
             # host-side paging state
             self._free_pages: List[int] = list(range(n_pages))
             self._pages_np = np.full((n_slots, max_len // page_size), -1,
@@ -187,7 +198,8 @@ class DecodeEngine:
             self.stats.update({
                 "pages_total": n_pages, "pages_in_use": 0,
                 "peak_pages_in_use": 0, "page_occupancy": 0.0,
-                "page_fragmentation": 0.0, "admission_deferred_pages": 0})
+                "page_fragmentation": 0.0, "admission_deferred_pages": 0,
+                "kv_blocks_issued": 0, "kv_blocks_live": 0})
         if prefix_share:
             self.stats.update({
                 "prefix_hits": 0, "prefix_misses": 0,
@@ -716,6 +728,8 @@ class DecodeEngine:
         self.stats["segments"] += 1
         self.stats["wasted_slot_steps"] += int(
             (~self.active).sum()) * self.segment
+        if self.paged:
+            self._count_kv_blocks()
         for slot in range(self.n_slots):
             if not self.active[slot]:
                 continue
@@ -732,6 +746,19 @@ class DecodeEngine:
                     # completed, delivered — but late
                     self.stats["deadline_miss"] += 1
                 self._free_slot(slot)               # slot freed for reuse
+
+    def _count_kv_blocks(self) -> None:
+        """The paged kernel's grid steps in the segment just run: every
+        slot's, and those whose rows hold a live position of an active
+        slot (at its t-th step a slot attends to ``index + t`` rows).  A
+        freed slot's steps are issued all the same: its device length
+        runs on from where it stopped."""
+        self.stats["kv_blocks_issued"] += (
+            self.n_slots * self._kv_steps * self.segment * self._kv_layers)
+        rows = (self._index_np[self.active][:, None]
+                + np.arange(1, self.segment + 1))
+        live = np.minimum(-(-rows // self._kv_rows), self._kv_steps)
+        self.stats["kv_blocks_live"] += int(live.sum()) * self._kv_layers
 
     def _free_slot_pages(self, slot: int) -> None:
         """Reclaim a freed slot's pages and reservation.  Each mapped

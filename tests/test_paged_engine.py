@@ -98,6 +98,49 @@ class TestFlashDecodePagedKernel:
             assert (np.asarray(out) == np.asarray(flat)).all()
 
 
+    @pytest.mark.parametrize("n", [1, 4, 5, 8])
+    @pytest.mark.parametrize("h,hkv,d", [(4, 4, 64), (4, 2, 128)],
+                             ids=["mha", "gqa"])
+    def test_multi_page_steps_match_gather_oracle(self, h, hkv, d, n,
+                                                  monkeypatch):
+        """``n`` pages per grid step over a 12-page table (5 and 8 leave
+        a partial last step), read at layer 1 of a stacked pool.  Slots:
+        lengths 1, PS - 1, PS, PS + 1 and one row either side of n * PS;
+        the full table; a freed slot whose table row is -1 and whose
+        stale length runs past the table (finite, no fault); a slot
+        with -1 entries inside its length (read from pool page 0, as the
+        oracle gathers them); and a full table whose length runs past
+        it, whose rows count once however the last step's page slots
+        past the table are mapped."""
+        from repro.kernels import flash_decode
+        from repro.kernels.ref import flash_decode_paged_ref
+        rng = np.random.default_rng(3)
+        ps, p_tab, n_pg, n_layers = 8, 12, 100, 2
+        monkeypatch.setattr(flash_decode, "KV_BLOCK_BYTES",
+                            n * ps * hkv * d * 4)      # float32 pages
+        lengths = [1, ps - 1, ps, ps + 1, n * ps - 1, n * ps + 1,
+                   p_tab * ps, p_tab * ps + 37, 5 * ps, p_tab * ps + 3]
+        b = len(lengths)
+        q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
+        pool_k, pool_v = (jnp.asarray(rng.standard_normal(
+            (n_layers, n_pg, ps, hkv * d)), jnp.float32) for _ in range(2))
+        pages = np.full((b, p_tab), -1, np.int32)
+        free = list(rng.permutation(n_pg))
+        for bi, n_rows in enumerate(lengths):
+            if bi == 7:                    # freed: row -1, length stale
+                continue
+            for pi in range(min(-(-n_rows // ps), p_tab)):
+                pages[bi, pi] = free.pop()
+        pages[8, [1, 3]] = -1
+        pages, lengths = jnp.asarray(pages), jnp.asarray(lengths, jnp.int32)
+        out = flash_decode.flash_decode_paged(
+            q, pool_k, pool_v, pages, lengths, jnp.int32(1), interpret=True)
+        ref = flash_decode_paged_ref(q, pool_k, pool_v, pages, lengths, 1)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+
+
 # ====================================================================== #
 # attention_decode paged branch
 # ====================================================================== #
@@ -317,6 +360,31 @@ class TestPagedEngine:
             out = eng.run()
             outs.append([out[r] for r in rids])
         assert outs[0] == outs[1]
+
+    def test_kv_block_stats_count_the_kernel_grid(self, monkeypatch):
+        """Two slots, two pages per kernel step (a 4-page table, so two
+        steps per slot); one request ends after one segment and frees
+        its slot while the other decodes on.  ``kv_blocks_issued``
+        counts both slots' steps in every segment, ``kv_blocks_live``
+        only those of the active slot that hold rows it attends to."""
+        from repro.kernels import flash_decode
+        cfg = _cfg("minicpm-2b")
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        page = 8 * cfg.n_kv_heads * cfg.head_dim * 4       # float32 rows
+        monkeypatch.setattr(flash_decode, "KV_BLOCK_BYTES", 2 * page)
+        eng = DecodeEngine(cfg, params, n_slots=2, max_len=32, segment=4,
+                           paged=True, page_size=8, n_pages=8)
+        rng = np.random.default_rng(0)
+        eng.submit(rng.integers(0, cfg.vocab, (5,)), 4)     # one segment
+        eng.submit(rng.integers(0, cfg.vocab, (14,)), 12)   # three
+        eng.run()
+        assert eng.stats["segments"] == 3
+        layers = cfg.n_layers
+        assert eng.stats["kv_blocks_issued"] == 3 * 2 * 2 * 4 * layers
+        # the slot attends to index + t rows at step t; 16 rows a step
+        live = sum(-(-(idx + t) // 16)
+                   for idx in (5, 14, 18, 22) for t in range(1, 5))
+        assert eng.stats["kv_blocks_live"] == live * layers
 
     def test_growth_and_reclaim_on_slot_reuse(self):
         """Pages are assigned lazily (prompt pages at admission, decode

@@ -11,7 +11,9 @@ check that a Mosaic kernel (``tpu_custom_call``) is in the program:
 * ``flash_decode`` and ``flash_decode_paged`` at minicpm-2b serving
   widths (8 slots, 2048-row cache, 16-row pages), and the paged kernel
   at qwen2-vl-2b's GQA widths (12 query heads over 2 kv heads x 128),
-  each over one layer's pool and over a pool stacked over layers;
+  each over one layer's pool and over a pool stacked over layers, with
+  as many pages per grid step as ``kv_grid`` gives those widths (and
+  at the serving widths that is several);
 * the paged engine's decode segment at minicpm-2b widths (4 layers),
   whose compiled program must write the stacked KV pool in place:
   no per-layer slab of it is sliced out, restacked or copied;
@@ -115,6 +117,16 @@ def test_flash_decode_paged(one_chip, h, h_kv, d, n_pages, layers):
     _compile(_decode.flash_decode_paged, one_chip,
              ((8, 1, h, d), BF16), pool, pool, ((8, 128), I32), ((8,), I32),
              ((), I32))
+
+
+def test_kv_grid_at_serving_widths():
+    """The serving cell's pool: 16-row pages of 2304 bf16 lanes, a
+    128-page table.  A grid step reads several pages, within the byte
+    budget of one K block, and the steps cover the table."""
+    n, steps = _decode.kv_grid(16, 2304, 128, 2)
+    assert n > 1
+    assert n * 16 * 2304 * 2 <= _decode.KV_BLOCK_BYTES
+    assert (steps - 1) * n < 128 <= steps * n
 
 
 def _results(text, shape):
